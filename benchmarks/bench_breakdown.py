@@ -82,4 +82,6 @@ def run(sf=4, scale=0.003):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import init_compile_cache
+    init_compile_cache()
     run()

@@ -136,4 +136,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import init_compile_cache
+    init_compile_cache()
     main()

@@ -75,4 +75,6 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import init_compile_cache
+    init_compile_cache()
     run()

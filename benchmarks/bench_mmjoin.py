@@ -42,4 +42,6 @@ def run(sizes=(256, 1024, 4096, 16384)):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import init_compile_cache
+    init_compile_cache()
     run()

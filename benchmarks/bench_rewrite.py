@@ -201,4 +201,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import init_compile_cache
+    init_compile_cache()
     main()

@@ -40,4 +40,6 @@ def run(sfs=(1, 2, 4)):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import init_compile_cache
+    init_compile_cache()
     run()

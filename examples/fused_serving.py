@@ -10,5 +10,7 @@ Run:  PYTHONPATH=src python examples/fused_serving.py
 from repro.launch.serve import run_serving
 
 if __name__ == "__main__":
+    from repro.compile_cache import init_compile_cache
+    init_compile_cache()
     run_serving(arch="smollm-360m", batch=4, decode_steps=8, k=96, l=8,
                 repeats=10)

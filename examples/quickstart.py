@@ -10,7 +10,7 @@ single fluent entry point, ``repro.core.query.Session``:
      over shared join+model work, ``num_groups="auto"``),
   3. ``.rows()`` row predictions, fused == non-fused (paper Eq. 1),
   4. ``.serve()`` the bucketed dynamic-batch runtime — including sharded
-     across a forced multi-device mesh, bit-identical to one device,
+     across a mesh of the devices present, bit-identical to one device,
   5. append dimension rows through the versioned ``Catalog`` — every cached
      plan and serving runtime refreshes *in place* (delta prefuse, zero
      recompiles), bit-identical to a cold rebuild,
@@ -31,23 +31,17 @@ single fluent entry point, ``repro.core.query.Session``:
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
-import os
-
-# Force 8 host devices so the sharded-serving section below has a real mesh
-# even on a laptop CPU.  Must happen before jax first initializes.
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
-
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import init_compile_cache
 from repro.core.fusion import LinearOperator
 from repro.core.laq import Table
 from repro.core.query import PREDICTION, Catalog, Session
 from repro.launch.mesh import make_serving_mesh
 
+init_compile_cache()
 rng = np.random.default_rng(0)
 
 # -- 1. Relations (a fact table + two dimension tables) ---------------------
@@ -117,7 +111,9 @@ print("fused == non-fused row predictions ✓", np.asarray(fused).ravel())
 # row-shards each prefused partial over the "model" axis (per-shard PK-index
 # slices → device-local probes + gathers, one psum) and shards the request
 # batch over "data"; the threshold is forced to 0 so the toy tables shard.
-mesh_sess = Session(catalog, mesh=make_serving_mesh((2, 4)),
+# The mesh spans the devices present (one on a plain CPU run; set
+# XLA_FLAGS=--xla_force_host_platform_device_count=N for N host devices).
+mesh_sess = Session(catalog, mesh=make_serving_mesh((1, len(jax.devices()))),
                     shard_threshold_bytes=0)
 serving = mesh_sess.bind(pipeline.build()).serve(buckets=(8, 64))
 reference = pipeline.serve(buckets=(8, 64))

@@ -49,7 +49,23 @@ class LinearOperator:
         return int(self.L.shape[1])
 
     def apply(self, x: jnp.ndarray) -> jnp.ndarray:
-        out = x @ self.L
+        out = jnp.matmul(x, self.L, precision="highest")
+        if self.bias is not None:
+            out = out + self.bias[None, :].astype(out.dtype)
+        return out
+
+    def apply_rows(self, x: jnp.ndarray) -> jnp.ndarray:
+        """``apply`` with one add order for every row, whatever the batch.
+
+        A dot's last bit can depend on the batch's row count and on a row's
+        position in it (blocked CPU kernels fuse multiply-adds on full row
+        blocks and not on the remainder), so two programs scoring one row
+        in different batch shapes could disagree.  The per-row serving
+        paths, which must agree bitwise across batch shapes and meshes,
+        sum the k products in feature order instead.
+        """
+        out = jnp.sum(x[:, :, None] * self.L[None, :, :].astype(x.dtype),
+                      axis=1)
         if self.bias is not None:
             out = out + self.bias[None, :].astype(out.dtype)
         return out
@@ -58,10 +74,11 @@ class LinearOperator:
         """Associativity: (X L₁) L₂ = X (L₁ L₂) — pre-fold chained layers."""
         bias = None
         if self.bias is not None:
-            bias = self.bias @ other.L
+            bias = jnp.matmul(self.bias, other.L, precision="highest")
         if other.bias is not None:
             bias = other.bias if bias is None else bias + other.bias
-        return LinearOperator(self.L @ other.L, bias)
+        return LinearOperator(
+            jnp.matmul(self.L, other.L, precision="highest"), bias)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,13 +104,18 @@ class DecisionTreeGEMM:
 
     def predicates(self, x: jnp.ndarray) -> jnp.ndarray:
         """Step 1–2: (X F > v) ∈ {0,1}^{i×p}."""
-        return (x @ self.F > self.v[None, :]).astype(x.dtype)
+        feats = jnp.matmul(x, self.F, precision="highest")
+        return (feats > self.v[None, :]).astype(x.dtype)
 
     def apply(self, x: jnp.ndarray) -> jnp.ndarray:
         """One-hot leaf encoding (i × l) — steps 1–4 of Fig. 5."""
         b = self.predicates(x)
         score = b @ self.H.astype(x.dtype)
         return (score == self.h[None, :].astype(x.dtype)).astype(x.dtype)
+
+    # Leaf encodings are exact (0/1 compares of small integer sums), so the
+    # per-row serving paths need no add-order guarantee beyond ``apply``.
+    apply_rows = apply
 
     def predict_leaf(self, x: jnp.ndarray) -> jnp.ndarray:
         """Leaf index per row (argmax over the one-hot encoding)."""

@@ -40,6 +40,14 @@ class PrefusedStar:
         return sum(int(p.size) * p.dtype.itemsize for p in self.partials)
 
 
+def _f32(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a @ b`` in full f32 (the TPU default would round to bf16).
+
+    The tree's ``preds @ H`` stays at the default: 0/1 times ±1 is exact.
+    """
+    return jnp.matmul(a, b, precision="highest")
+
+
 def _feature_slices(dims: Sequence[DimSpec]):
     """[start, stop) of each dimension's block in T's k feature columns."""
     out = []
@@ -61,7 +69,7 @@ def prefuse_dims(dims: Sequence[DimSpec], model: Model) -> PrefusedStar:
     parts = []
     if isinstance(model, LinearOperator):
         for j, (d, m) in enumerate(zip(dims, mats)):
-            part = d.dim.matrix @ (m @ model.L)              # B M L
+            part = _f32(d.dim.matrix, _f32(m, model.L))     # B M L
             if j == 0 and model.bias is not None:
                 # Constant term lives in arm 0's partial: a row missing any
                 # arm is invalid and zeroed after the sum, so the bias
@@ -74,7 +82,7 @@ def prefuse_dims(dims: Sequence[DimSpec], model: Model) -> PrefusedStar:
     f_owner = jnp.argmax(model.F, axis=0)                     # feature per node
     for d, m, (lo, hi) in zip(dims, mats, slices):
         own = ((f_owner >= lo) & (f_owner < hi)).astype(jnp.float32)  # (p,)
-        feats = d.dim.matrix @ (m @ model.F)                  # (r_j, p)
+        feats = _f32(d.dim.matrix, _f32(m, model.F))         # (r_j, p)
         preds = (feats > model.v[None, :]).astype(jnp.float32) * own[None, :]
         parts.append(preds @ model.H)                         # (r_j, l)
     return PrefusedStar(tuple(parts), model.h)
@@ -100,7 +108,7 @@ def prefuse_rows(dims: Sequence[DimSpec], model: Model, j: int,
     d, m = dims[j], mats[j]
     rows = jnp.take(d.dim.matrix, jnp.asarray(row_ids, jnp.int32), axis=0)
     if isinstance(model, LinearOperator):
-        out = rows @ (m @ model.L)
+        out = _f32(rows, _f32(m, model.L))
         if j == 0 and model.bias is not None:   # matches prefuse_dims
             out = out + model.bias[None, :].astype(out.dtype)
         return out
@@ -108,7 +116,7 @@ def prefuse_rows(dims: Sequence[DimSpec], model: Model, j: int,
     lo, hi = slices[j]
     f_owner = jnp.argmax(model.F, axis=0)
     own = ((f_owner >= lo) & (f_owner < hi)).astype(jnp.float32)
-    feats = rows @ (m @ model.F)
+    feats = _f32(rows, _f32(m, model.F))
     preds = (feats > model.v[None, :]).astype(jnp.float32) * own[None, :]
     return preds @ model.H
 
@@ -150,7 +158,7 @@ def predict_fused_matmul(star: StarJoin, pre: PrefusedStar) -> jnp.ndarray:
     """Paper-faithful online phase: dense Iⱼ matmuls (small inputs only)."""
     acc = None
     for d, fj, p in zip(star.dims, star.joins, pre.partials):
-        part = fj.dense(d.dim.capacity) @ p
+        part = _f32(fj.dense(d.dim.capacity), p)
         acc = part if acc is None else acc + part
     acc = acc * star.row_valid[:, None]
     if pre.h is None:

@@ -60,7 +60,7 @@ def groupby_sum_matmul(keys_r: jnp.ndarray, values_r: jnp.ndarray,
     mat_s = jnp.minimum(mat_s, 1.0)                    # de-duplicate keys
     # ones @ MAT_R @ MAT_Sᵀ : reduce rows, then map domain slots to groups.
     per_slot = jnp.sum(mat_r, axis=0)                  # (n_dom,)
-    sums = mat_s @ per_slot                            # (num_groups,)
+    sums = jnp.matmul(mat_s, per_slot, precision="highest")  # (num_groups,)
     return grp_vals, sums
 
 
@@ -273,7 +273,8 @@ def matmul_aggregate(gid: jnp.ndarray, values: jnp.ndarray,
     mirroring the padded-key handling of ``onehot_keys``.
     """
     onehot = (gid[:, None] == jnp.arange(num_groups)[None, :])
-    return onehot.astype(values.dtype).T @ values
+    return jnp.matmul(onehot.astype(values.dtype).T, values,
+                      precision="highest")
 
 
 def decode_composite(codes: jnp.ndarray, bounds: Sequence[int]

@@ -273,8 +273,8 @@ def materialize_matmul(I: jnp.ndarray, r: Table, s: Table, capacity: int
     """Paper-faithful materialization: T = [I_R @ R.matrix | I_S @ S.matrix]."""
     ii, jj, nnz = matching_pairs(I, capacity)
     i_r, i_s = row_mapping_matrices(ii, jj, r.capacity, s.capacity)
-    left = i_r @ r.matrix
-    right = i_s @ s.matrix
+    left = jnp.matmul(i_r, r.matrix, precision="highest")
+    right = jnp.matmul(i_s, s.matrix, precision="highest")
     cols = tuple(f"{r.name}.{c}" for c in r.columns) + tuple(
         f"{s.name}.{c}" for c in s.columns)
     keys = {}

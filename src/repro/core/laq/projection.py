@@ -11,6 +11,12 @@ Two paths:
   * ``project_gather`` — the TPU-optimized path: column projection is a
     gather of columns; XLA lowers it to a zero-FLOP slice/copy.
 Both are exposed; tests assert they agree.
+
+Every f32 matmul here and in the modules that compose with it runs at
+``precision="highest"``: on a TPU the default precision rounds f32 operands
+to bf16, which would round every projected value to 8 mantissa bits.  Only
+products whose operands are exact in bf16 by construction (one-hot by
+one-hot, 0/1 by ±1) keep the default.
 """
 from __future__ import annotations
 
@@ -32,10 +38,17 @@ def mapping_matrix(source_cols: Sequence[str], target_cols: Sequence[str],
     return m
 
 
+def project_columns(matrix: jnp.ndarray, source_cols: Sequence[str],
+                    target_cols: Sequence[str]) -> jnp.ndarray:
+    """``matrix · M`` for the mapping ``source_cols → target_cols``, in f32."""
+    return jnp.matmul(matrix, mapping_matrix(source_cols, target_cols,
+                                             matrix.dtype),
+                      precision="highest")
+
+
 def project_matmul(table: Table, target_cols: Sequence[str]) -> Table:
     """Paper-faithful projection: one (r×c)·(c×k) matmul on the MXU."""
-    m = mapping_matrix(table.columns, target_cols, table.matrix.dtype)
-    out = table.matrix @ m
+    out = project_columns(table.matrix, table.columns, target_cols)
     keys = {c: v for c, v in table.keys.items() if c in target_cols}
     return Table(table.name, tuple(target_cols), out, keys, table.nvalid)
 

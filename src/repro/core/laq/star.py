@@ -14,7 +14,7 @@ from typing import Sequence, Tuple
 import jax.numpy as jnp
 
 from .join import FactoredJoin, join_factored
-from .projection import mapping_matrix
+from .projection import project_columns
 from .table import Table
 
 
@@ -57,8 +57,8 @@ class StarJoin:
         """
         parts = []
         for d, fj in zip(self.dims, self.joins):
-            proj = d.dim.matrix @ mapping_matrix(
-                d.dim.columns, d.feature_cols)          # Bⱼ Mⱼ
+            proj = project_columns(d.dim.matrix, d.dim.columns,
+                                   d.feature_cols)      # Bⱼ Mⱼ
             parts.append(fj.apply(proj))                # Iⱼ (Bⱼ Mⱼ)
         t = jnp.concatenate(parts, axis=1)
         return t * self.row_valid[:, None].astype(t.dtype)
@@ -69,7 +69,9 @@ class StarJoin:
         out = jnp.zeros((self.fact.capacity, k), jnp.float32)
         for d, fj, m in zip(self.dims, self.joins, self.mapping_matrices()):
             i_dense = fj.dense(d.dim.capacity)          # (r_fact, r_dim)
-            out = out + i_dense @ (d.dim.matrix @ m)    # Iⱼ Bⱼ Mⱼ
+            out = out + jnp.matmul(                     # Iⱼ Bⱼ Mⱼ
+                i_dense, jnp.matmul(d.dim.matrix, m, precision="highest"),
+                precision="highest")
         return out * self.row_valid[:, None]
 
 

@@ -49,7 +49,7 @@ from ..laq.aggregation import (auto_num_groups, composite_code,
                                segment_aggregate, segment_reduce)
 from ..laq.catalog import Catalog, CatalogHistoryError, changed_spans
 from ..laq.join import FactoredJoin, PKIndex, pk_index
-from ..laq.projection import mapping_matrix
+from ..laq.projection import project_columns
 from ..laq.selection import select
 from ..laq.star import DimSpec, StarJoin
 from ..laq.table import PAD_KEY, Table
@@ -58,7 +58,7 @@ from .ir import (AGG_OPS, PREDICTION, Aggregate, ArmSpec, PredictiveQuery,
                  eval_value)
 from .multiquery import holds_tracers
 from .planner import (QueryPlan, effective_serve_backend,
-                      estimate_query_cost, place_tables,
+                      estimate_query_cost, place_tables, plan_fact_backend,
                       plan_chain_materialization, plan_query, plan_streaming,
                       resolve_mesh_serve_backend)
 from .rewrite import _FILTER_FNS, rewrite_query
@@ -450,8 +450,8 @@ class CompiledQuery:
         state = _query_state(star, prefused, gid)
         if self._sp is not None:
             tables = (list(prefused.partials) if self.backend == "fused"
-                      else [d.dim.matrix
-                            @ mapping_matrix(d.dim.columns, d.feature_cols)
+                      else [project_columns(d.dim.matrix, d.dim.columns,
+                                            d.feature_cols)
                             for d in star.dims])
             state["sharded"] = predict_rows_state(
                 self._sp, tables, [fj.ptr for fj in star.joins],
@@ -736,8 +736,10 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
     ("auto" defers to the cost model); explicit "matmul" backends give the
     paper-faithful reference lowering used by tests and benchmarks.
     ``serve_backend`` picks the physical kernel for the *serving* paths —
-    ``predict_rows`` always, and ``predictions`` when the join backend is
-    "gather" (the dense "matmul" join is its own paper-faithful lowering):
+    ``predict_rows`` always, and ``predictions``/``run`` when the join
+    backend is "gather" (the dense "matmul" join is its own paper-faithful
+    lowering) and, for the fused gather, the fact fits one kernel call
+    (:func:`~.planner.plan_fact_backend`, named in ``plan.reason``):
     "pallas" lowers the fused gather-sum onto ``fused_star_gather`` and
     non-fused trees onto ``tree_predict`` ("auto" picks it on TPU when the
     shapes fit the block specs); ``interpret=True`` runs the kernels in
@@ -983,6 +985,11 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
             plan, serve_backend=serve_backend,
             reason=f"{plan.reason}; serve={serve_backend} (caller override)")
 
+    fact_backend, why = plan_fact_backend(serve_backend, backend,
+                                          len(star.dims), fact.capacity)
+    if why:
+        plan = dataclasses.replace(plan, reason=f"{plan.reason}; {why}")
+
     prefused = None
     partial_keys = ()
     if q.model is not None and backend == "fused":
@@ -1010,13 +1017,13 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
         if backend == "fused":
             if join_backend != "gather":
                 return predict_fused_matmul(star_v, pre_v)
-            if serve_backend == "pallas":
+            if fact_backend == "pallas":
                 return predict_fused_kernel(star_v, pre_v,
                                             interpret=interpret)
             return predict_fused(star_v, pre_v)
         if join_backend != "gather":
             return predict_nonfused_matmul(star_v, model)
-        if serve_backend == "pallas":   # resolve_ guarantees a tree model
+        if fact_backend == "pallas":   # resolve_ guarantees a tree model
             return predict_nonfused_kernel(star_v, model,
                                            interpret=interpret)
         return predict_nonfused(star_v, model)
@@ -1157,7 +1164,8 @@ def _make_predict_rows_sharded(star: StarJoin, model,
         tables = list(prefused.partials)
         h = prefused.h
     else:
-        tables = [d.dim.matrix @ mapping_matrix(d.dim.columns, d.feature_cols)
+        tables = [project_columns(d.dim.matrix, d.dim.columns,
+                                  d.feature_cols)
                   for d in star.dims]
         h = None
     specs, plan = place_tables(mesh, tables, plan, axis=shard_axis,
@@ -1218,7 +1226,7 @@ def _make_predict_rows(star: StarJoin, model, backend: str,
         parts = []
         for d, mat, ptr0, found0 in zip(star.dims, state["dim_mats"],
                                         state["ptrs"], state["founds"]):
-            proj = mat @ mapping_matrix(d.dim.columns, d.feature_cols)
+            proj = project_columns(mat, d.dim.columns, d.feature_cols)
             ptr = jnp.take(ptr0, row_ids)
             hit = jnp.take(found0, row_ids)
             parts.append(jnp.take(proj, ptr, axis=0)
@@ -1229,7 +1237,7 @@ def _make_predict_rows(star: StarJoin, model, backend: str,
             out = tree_predict(t, model.F, model.v, model.H, model.h,
                                interpret=interpret)
         else:
-            out = model.apply(t)
+            out = model.apply_rows(t)
         return out * v[:, None].astype(out.dtype)
     return fn
 

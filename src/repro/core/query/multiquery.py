@@ -59,7 +59,7 @@ from ..fusion.operators import DecisionTreeGEMM, LinearOperator
 from ..fusion.pipeline import _feature_slices, prefuse_dims, prefuse_rows
 from ..laq.catalog import Catalog, CatalogHistoryError, changed_spans
 from ..laq.join import FactoredJoin, PKIndex, pk_index
-from ..laq.projection import mapping_matrix
+from ..laq.projection import project_columns
 from ..laq.star import DimSpec
 from ..laq.table import PAD_KEY, Table
 from .ir import ArmSpec, Model, PredictiveQuery
@@ -428,7 +428,7 @@ class ArtifactPool:
 
         def build():
             dim = self.catalog[table]
-            return dim.matrix @ mapping_matrix(dim.columns, feature_cols)
+            return project_columns(dim.matrix, dim.columns, feature_cols)
         entry = self._fresh(
             features_key(table, feature_cols), "features", (table,),
             build, {"table": table, "feature_cols": feature_cols})
@@ -650,7 +650,7 @@ class ArtifactPool:
     def _rebuild_features(self, entry):
         s = entry.spec
         dim = self.catalog[s["table"]]
-        return dim.matrix @ mapping_matrix(dim.columns, s["feature_cols"])
+        return project_columns(dim.matrix, dim.columns, s["feature_cols"])
 
     def _refresh_features(self, entry, deltas):
         s = entry.spec
@@ -658,8 +658,9 @@ class ArtifactPool:
         if ids is not None:
             ids = self._pad_ids(ids)
             dim = self.catalog[s["table"]]
-            m = mapping_matrix(dim.columns, s["feature_cols"])
-            rows = jnp.take(dim.matrix, jnp.asarray(ids), axis=0) @ m
+            rows = project_columns(
+                jnp.take(dim.matrix, jnp.asarray(ids), axis=0), dim.columns,
+                s["feature_cols"])
             entry.value = entry.value.at[jnp.asarray(ids)].set(rows)
 
     def _hop_source_for(self, entry):

@@ -22,6 +22,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
+from ...kernels.fused_star_gather.kernel import max_rows_per_call
 from ...launch.sharding import safe_spec
 from ..fusion.operators import DecisionTreeGEMM
 from ..fusion.planner import FusionDecision, plan_fusion
@@ -238,6 +239,27 @@ def plan_serving_backend(model: Optional[Model], num_arms: int, *,
         return "jnp", (f"tree p={model.p}/l={model.l} exceeds tree_predict "
                        "block bounds")
     return "jnp", "nonfused linear head: XLA matmul already optimal"
+
+
+def plan_fact_backend(serve_backend: str, backend: str, num_arms: int,
+                      fact_rows: int) -> Tuple[str, str]:
+    """Physical backend of the fact-sized prediction program; ``(b, why)``.
+
+    ``run()``/``predictions()`` score every fact row at once.  The fused
+    ``fused_star_gather`` kernel holds one call's (J, n) pointers in SMEM,
+    so it takes the fact axis only when it fits one call; a larger fact
+    gathers in jnp (the kernel would run one grid step per fact row over
+    hundreds of chunked calls).  The serving paths keep the kernel: their
+    batches are request-sized.  ``why`` is empty when nothing changed.
+    """
+    if serve_backend != "pallas" or backend != "fused":
+        return serve_backend, ""
+    rows = max_rows_per_call(num_arms)
+    if fact_rows <= rows:
+        return "pallas", ""
+    return "jnp", (f"run=jnp (fact pointers {num_arms}x{fact_rows} exceed "
+                   f"one fused_star_gather call's SMEM, {rows} rows; "
+                   "serving keeps the kernel)")
 
 
 def resolve_serve_backend(serve_backend: str, backend: str, model) -> str:

@@ -121,7 +121,7 @@ class _Pending:
     future: Future
     t_submit: float
     served: int = 0
-    parts: List[np.ndarray] = dataclasses.field(default_factory=list)
+    parts: List[jnp.ndarray] = dataclasses.field(default_factory=list)
 
 
 class _PlanQueue:
@@ -564,15 +564,13 @@ class AdmissionScheduler:
                 if s == 0 and c == p.n:
                     self._resolve(plan, p, seg, done)
                 else:
-                    # Chunked request: segments assemble on host (matches
-                    # the oversized path of ``serve``, incl. the sharded
-                    # eager-concat miscompile workaround).
-                    p.parts.append(np.asarray(seg))
+                    # Chunked request: segments concatenate once the last
+                    # one is served (matches the oversized path of
+                    # ``serve``).
+                    p.parts.append(seg)
                     if p.served == p.n:
-                        self._resolve(
-                            plan, p,
-                            jnp.asarray(np.concatenate(p.parts, axis=0)),
-                            done)
+                        self._resolve(plan, p,
+                                      jnp.concatenate(p.parts, axis=0), done)
             plan.steps += 1
             plan.admitted_rows += total
             plan.padded_rows += bucket - total

@@ -73,7 +73,7 @@ from ..fusion.operators import DecisionTreeGEMM
 from ..fusion.pipeline import prefuse_dims, prefuse_rows
 from ..laq.catalog import Catalog, CatalogHistoryError, changed_spans
 from ..laq.join import PKIndex, pk_index
-from ..laq.projection import mapping_matrix
+from ..laq.projection import project_columns
 from ..laq.star import DimSpec
 from ..laq.table import PAD_KEY, Table
 from .explain import ExplainReport
@@ -310,12 +310,9 @@ class ServingRuntime:
         return [{b: s * 1e3 for b, s in gen.items()}
                 for gen in self._compile_log]
 
-    def jit_cache_size(self) -> Optional[int]:
-        """The jit executable cache size (None if jax hides it)."""
-        try:
-            return self._jit._cache_size()
-        except AttributeError:
-            return None
+    def jit_cache_size(self) -> int:
+        """The number of executables in the bucket programs' jit cache."""
+        return self._jit._cache_size()
 
     def latency_stats(self) -> Dict[object, Dict[str, float]]:
         """Per-bucket steady-state serve latency percentiles (ms).
@@ -403,7 +400,7 @@ class ServingRuntime:
             m = self._model
             return tree_predict(t, m.F, m.v, m.H, m.h,
                                 interpret=self._interpret)
-        return self._model.apply(t)
+        return self._model.apply_rows(t)
 
     # -- introspection / lifecycle -------------------------------------------
     def _pool_keys(self) -> list:
@@ -626,9 +623,9 @@ class ServingRuntime:
                     rows = prefuse_rows(dims, self._model, j,
                                         jnp.asarray(upd))
                 else:
-                    m = mapping_matrix(dim.columns, arm.feature_cols)
-                    rows = jnp.take(dim.matrix, jnp.asarray(upd),
-                                    axis=0) @ m
+                    rows = project_columns(
+                        jnp.take(dim.matrix, jnp.asarray(upd), axis=0),
+                        dim.columns, arm.feature_cols)
                 table = table.at[jnp.asarray(upd)].set(rows)
             ids = np.asarray(touched, np.int32)
             lo, hi = int(ids.min()), int(ids.max()) + 1
@@ -681,16 +678,9 @@ class ServingRuntime:
             chunks = [self._serve_bucketed([f[i:i + top] for f in fks],
                                            record=False)
                       for i in range(0, n, top)]
-            if self.sharded is not None:
-                # Eagerly concatenating mesh-sharded chunks miscompiles on
-                # some jax versions (observed: values scaled by the model
-                # axis size) — assemble oversized batches on host instead.
-                out = jnp.asarray(np.concatenate(
-                    [np.asarray(c) for c in chunks], axis=0))
-            else:
-                out = jnp.concatenate(chunks, axis=0)
-                if self._sync_stats:
-                    jax.block_until_ready(out)
+            out = jnp.concatenate(chunks, axis=0)
+            if self._sync_stats:
+                jax.block_until_ready(out)
             self._lat_chunked.append(time.perf_counter() - t0)
             return out
         return self._serve_bucketed(fks)
@@ -861,7 +851,7 @@ def _serving_artifacts(catalog: Mapping[str, Table], q: PredictiveQuery,
             tables = tuple(tables)
         else:
             tables = tuple(
-                d.dim.matrix @ mapping_matrix(d.dim.columns, d.feature_cols)
+                project_columns(d.dim.matrix, d.dim.columns, d.feature_cols)
                 for d in dims)
         h = None
 

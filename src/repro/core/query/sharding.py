@@ -38,12 +38,8 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # moved to the jax namespace in newer releases
-    from jax import shard_map
-except ImportError:  # jax <= 0.4/0.5 keeps it under experimental
-    from jax.experimental.shard_map import shard_map
 
 from ...launch.mesh import dp_axes
 from ..fusion.operators import DecisionTreeGEMM, LinearOperator
@@ -51,19 +47,14 @@ from ..laq.join import PKIndex, pk_index, shard_pk_index
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (the rep-check kwarg was renamed).
+    """``shard_map`` with the replication check off.
 
-    The replication check is disabled explicitly: the forward programs end
-    in a ``psum`` over the shard axis, which guarantees the out-spec's
-    replication but which older checkers cannot always prove through the
-    mixed replicated/sharded arm state.
+    The forward programs end in a ``psum`` over the shard axis, which
+    guarantees the out-spec's replication, but the checker cannot prove it
+    through the mixed replicated/sharded arm state.
     """
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 def _rep_spec(x) -> P:
@@ -234,7 +225,7 @@ def _accumulate(parts, hits, valid, h, model, backend):
     else:
         t = jnp.concatenate(parts, axis=1) * valid[:, None].astype(
             jnp.float32)
-        out = model.apply(t)
+        out = model.apply_rows(t)
     return out * valid[:, None].astype(out.dtype)
 
 
@@ -441,7 +432,7 @@ def make_predict_rows_forward(sp: ShardedPrefusedPartials, model,
         else:
             t = jnp.concatenate(parts, axis=1) * v[:, None].astype(
                 jnp.float32)
-            out = mdl.apply(t) * v[:, None].astype(jnp.float32)
+            out = mdl.apply_rows(t) * v[:, None].astype(jnp.float32)
         bad = jnp.isnan(poison)[:, None]
         return jnp.where(bad, poison[:, None].astype(out.dtype), out)
 

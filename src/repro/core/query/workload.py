@@ -333,12 +333,28 @@ def _np_model(model, x: np.ndarray) -> np.ndarray:
             ).astype(np.float64)
 
 
+def _np_lookup(pk: np.ndarray, fk: np.ndarray) -> np.ndarray:
+    """Row of ``pk`` holding each ``fk`` value, -1 where no row does.
+
+    A direct-address table over the key range: one slot per key value, the
+    last row holding a value winning (as a dict built in row order would).
+    """
+    if pk.size == 0:
+        return np.full(fk.shape, -1, np.int64)
+    lo, hi = int(pk.min()), int(pk.max())
+    slots = np.full(hi - lo + 1, -1, np.int64)
+    slots[pk.astype(np.int64) - lo] = np.arange(pk.size)
+    off = fk.astype(np.int64) - lo
+    hit = (off >= 0) & (off <= hi - lo)
+    return np.where(hit, slots[np.clip(off, 0, hi - lo)], -1)
+
+
 def _np_resolve(tables: Dict[str, Table], q: PredictiveQuery):
     """Per-fact-row chain resolution: validity, features, per-table ptrs.
 
-    The oracle resolves every hop with a dict lookup per row — no factored
-    joins, no composition — so agreement with the engine genuinely
-    cross-checks the algebra.  Returns ``(valid, feats, ptrs, keymaps)``:
+    The oracle resolves every hop with a direct-address lookup per row —
+    no factored joins, no composition — so agreement with the engine
+    genuinely cross-checks the algebra.  Returns ``(valid, feats, ptrs, keymaps)``:
     ``ptrs[name]`` is the fact-granularity row pointer into table ``name``
     (clipped to 0 on misses; misses are already folded into ``valid``).
     """
@@ -363,15 +379,12 @@ def _np_resolve(tables: Dict[str, Table], q: PredictiveQuery):
             prev = lk.table
         for name, parent, fk_col, pk_col, fcols_t, preds in chain:
             dcols, dkeys = _np_views(tables[name])
-            pkmap = {int(k): i for i, k in enumerate(dkeys[pk_col])}
             if parent is None:
-                fk = fkeys[fk_col]
-                ptr = np.asarray([pkmap.get(int(k), -1) for k in fk])
+                ptr = _np_lookup(dkeys[pk_col], fkeys[fk_col])
             else:
                 pfk = keymaps[parent][fk_col]
                 pptr = ptrs[parent]
-                ptr = np.asarray([pkmap.get(int(pfk[j]), -1)
-                                  for j in np.clip(pptr, 0, None)])
+                ptr = _np_lookup(dkeys[pk_col], pfk[np.clip(pptr, 0, None)])
                 ptr = np.where(pptr < 0, -1, ptr)
             ok = ptr >= 0
             if preds:
@@ -426,9 +439,13 @@ def np_oracle(tables: Dict[str, Table], q: PredictiveQuery) -> dict:
 
     group_rows: Optional[Dict[int, np.ndarray]] = None
     if q.group_keys:
-        group_rows = {}
-        for i in np.nonzero(valid)[0]:
-            group_rows.setdefault(int(codes[i]), []).append(int(i))
+        # Valid rows by group code, each group's rows in ascending order
+        # (a stable sort), so every per-group reduction below sees the rows
+        # in the same order as a row-by-row scan would give it.
+        rows = np.nonzero(valid)[0]
+        rows = rows[np.argsort(codes[rows], kind="stable")]
+        ucodes, starts = np.unique(codes[rows], return_index=True)
+        group_rows = dict(zip(ucodes.tolist(), np.split(rows, starts[1:])))
 
     def reduce(arr: np.ndarray, op: str) -> np.ndarray:
         if op == "count":
@@ -466,6 +483,20 @@ def np_oracle(tables: Dict[str, Table], q: PredictiveQuery) -> dict:
     return {"rows": int(valid.sum()), "scalars": scalars, "groups": groups}
 
 
+def np_row_oracle(tables: Dict[str, Table], q: PredictiveQuery
+                  ) -> np.ndarray:
+    """Per-fact-row prediction reference: model(features) × row validity.
+
+    The validity is the whole query's (fact predicates, joins, chains and
+    dimension predicates) — what ``CompiledQuery.predict_rows`` returns.
+    """
+    valid, feats, _, _ = _np_resolve(tables, q)
+    n = valid.shape[0]
+    x = np.stack(feats, axis=1) if feats else np.zeros((n, 0), np.float64)
+    out = _np_model(q.model, x)
+    return out * valid[:, None]
+
+
 def np_serving_oracle(tables: Dict[str, Table], q: PredictiveQuery
                       ) -> np.ndarray:
     """Per-fact-row serving reference: model(features) × arm validity.
@@ -473,12 +504,7 @@ def np_serving_oracle(tables: Dict[str, Table], q: PredictiveQuery
     Serving ignores fact-side predicates (requests are FK tuples), so only
     the join/chain/dimension-predicate validity gates each row.
     """
-    q_nofact = dataclasses.replace(q, fact_preds=())
-    valid, feats, _, _ = _np_resolve(tables, q_nofact)
-    n = valid.shape[0]
-    x = np.stack(feats, axis=1) if feats else np.zeros((n, 0), np.float64)
-    out = _np_model(q.model, x)
-    return out * valid[:, None]
+    return np_row_oracle(tables, dataclasses.replace(q, fact_preds=()))
 
 
 # --------------------------------------------------------------------------

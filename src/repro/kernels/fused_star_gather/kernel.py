@@ -10,17 +10,22 @@ HBM→VMEM — the same indirect-DMA pattern TPU embedding lookups use.  No
 row-matching matrix, no materialized join result, no intermediate HBM
 round-trips.
 
-Grid: (n/bn,) row blocks. Each step DMAs ``bn`` rows from each of the J
-pre-fused partials (rows of a block are fetched via a per-row index map on a
-(1, l)-shaped inner block — Pallas coalesces consecutive DMAs), adds them,
-applies the optional ``== h`` compare, and writes the (bn, l) output block.
+Grid: (n,) — one fact row per grid step, J+1 row-DMAs per step, all
+double-buffered by the Pallas pipeline.  VMEM per step: (J+1)·l floats —
+trivially small; the kernel is DMA-latency-bound, which is exactly the
+roofline position the paper's fusion puts the online phase in (it removed
+all the FLOPs).
 
-Implementation note: Pallas BlockSpec index maps must return *block* indices,
-so we use block shape (1, l) with grid (n,) — one fact row per grid step,
-J+1 row-DMAs per step, all double-buffered by the Pallas pipeline.  VMEM per
-step: (J+1)·l floats — trivially small; the kernel is DMA-latency-bound,
-which is exactly the roofline position the paper's fusion puts the online
-phase in (it removed all the FLOPs).
+Layout: the TPU lowering requires a block's last two dimensions to be
+multiples of (8, 128) or equal to the array's own.  A one-row block of an
+(r, l) table meets neither, so the tables and the output are viewed as
+(r, 1, l): the block (1, 1, l) then equals the array in its last two
+dimensions, and the leading (row) dimension is indexed freely by the
+prefetched pointer.
+
+SMEM: the scalar-prefetched (J, n) pointer and liveness arrays live in SMEM
+for the whole call, so one call takes at most :func:`max_rows_per_call`
+rows; the wrapper maps longer batches over chunks of that size.
 """
 from __future__ import annotations
 
@@ -31,6 +36,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+# SMEM the prefetched pointers may take: half of a v5e core's 1 MiB, leaving
+# the rest to the pipeline's own scalars.
+SMEM_POINTER_BYTES = 1 << 19
+
+
+def max_rows_per_call(n_dims: int) -> int:
+    """Rows whose (J, n) int32 pointers + liveness fit the SMEM budget.
+
+    The compiler pads the J axis of each prefetched array; rounding J up to
+    8 keeps the estimate above what it allocates.
+    """
+    return SMEM_POINTER_BYTES // (2 * 4 * (-(-n_dims // 8) * 8))
 
 
 def _star_gather_kernel(*refs, n_dims: int, compare: bool):
@@ -56,17 +75,18 @@ def fused_star_gather_pallas(ptrs: jnp.ndarray, found: jnp.ndarray,
                              interpret: bool = False) -> jnp.ndarray:
     """out[i] = Σⱼ tables[j][ptrs[j, i]] · found[j, i]  (== h if given).
 
-    ptrs/found: (J, n) int32; tables[j]: (r_j, l); h: (l,) or None.
+    ptrs/found: (J, n) int32; tables[j]: (r_j, l); h: (l,) or None;
+    n at most ``max_rows_per_call(J)``.
     """
     n_dims, n = ptrs.shape
     l = tables[0].shape[1]
     compare = h is not None
 
     in_specs = [
-        pl.BlockSpec((1, l), functools.partial(_tbl_index, j))
+        pl.BlockSpec((None, 1, l), functools.partial(_tbl_index, j))
         for j in range(n_dims)
     ]
-    inputs = list(tables)
+    inputs = [t.reshape(t.shape[0], 1, l) for t in tables]
     if compare:
         in_specs.append(pl.BlockSpec((1, l), lambda i, ptrs, found: (0, 0)))
         inputs.append(h.reshape(1, l))
@@ -75,18 +95,19 @@ def fused_star_gather_pallas(ptrs: jnp.ndarray, found: jnp.ndarray,
         num_scalar_prefetch=2,
         grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, l), lambda i, ptrs, found: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, l),
+                               lambda i, ptrs, found: (i, 0, 0)),
     )
     kernel = functools.partial(_star_gather_kernel, n_dims=n_dims,
                                compare=compare)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, l), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n, 1, l), jnp.float32),
         interpret=interpret,
-    )(ptrs, found, *inputs)
+    )(ptrs, found, *inputs).reshape(n, l)
 
 
 def _tbl_index(j, i, ptrs_ref, found_ref):
     """Row block of table j for fact row i: the prefetched FK pointer."""
-    return (ptrs_ref[j, i], 0)
+    return (ptrs_ref[j, i], 0, 0)

@@ -1,7 +1,9 @@
 """jit'd public wrapper for fused_star_gather.
 
-Clips pointers into range (liveness is carried by ``found``) and pads the
-output width to the fp32 lane multiple (128) before invoking the kernel.
+Clips pointers into range (liveness is carried by ``found``), pads the
+output width to the fp32 lane multiple (128), and maps batches longer than
+one call's SMEM-resident pointers over chunks of ``max_rows_per_call``
+rows.
 """
 from __future__ import annotations
 
@@ -11,7 +13,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from .kernel import fused_star_gather_pallas
+from .kernel import fused_star_gather_pallas, max_rows_per_call
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -48,6 +50,23 @@ def fused_star_gather(ptrs: jnp.ndarray, found: jnp.ndarray,
     for j, t in enumerate(tabs):
         clipped.append(jnp.clip(ptrs[j], 0, t.shape[0] - 1))
     ptrs_c = jnp.stack(clipped).astype(jnp.int32)
-    out = fused_star_gather_pallas(ptrs_c, found.astype(jnp.int32), tabs,
-                                   hh, interpret=interpret)
-    return out[:, :l]
+    found_c = found.astype(jnp.int32)
+    rows = max_rows_per_call(len(tabs))
+    if n <= rows:
+        out = fused_star_gather_pallas(ptrs_c, found_c, tabs, hh,
+                                       interpret=interpret)
+        return out[:, :l]
+    # Chunks of ``rows`` rows, one kernel call each; padded tail rows are
+    # dead (found = 0) and sliced away.
+    pad = (-n) % rows
+    n_dims = len(tabs)
+
+    def chunked(x):
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+        return x.reshape(n_dims, -1, rows).transpose(1, 0, 2)
+
+    out = jax.lax.map(
+        lambda pf: fused_star_gather_pallas(pf[0], pf[1], tabs, hh,
+                                            interpret=interpret),
+        (chunked(ptrs_c), chunked(found_c)))
+    return out.reshape(-1, out.shape[-1])[:n, :l]

@@ -23,6 +23,7 @@ from jax.experimental import pallas as pl
 def _tree_predict_kernel(x_ref, f_ref, v_ref, h_ref, hsum_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)                    # (bn, k)
     feats = jnp.dot(x, f_ref[...].astype(jnp.float32),
+                    precision="highest",
                     preferred_element_type=jnp.float32)   # (bn, p)
     preds = (feats > v_ref[...].astype(jnp.float32)).astype(jnp.float32)
     score = jnp.dot(preds, h_ref[...].astype(jnp.float32),

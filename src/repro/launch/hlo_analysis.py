@@ -36,15 +36,8 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    jax ≤ 0.4.x returns a list with one properties-dict per partition (often
-    ``[{...}]``); newer versions return the dict directly.  Returns a single
-    flat dict (first partition), ``{}`` when unavailable.
-    """
+    """``Compiled.cost_analysis()`` as a dict, ``{}`` when unavailable."""
     costs = compiled.cost_analysis()
-    if isinstance(costs, (list, tuple)):
-        costs = costs[0] if costs else {}
     return dict(costs) if costs else {}
 
 _SHAPE_RE = re.compile(r"(\w+)\[([0-9,]*)\]")

@@ -10,7 +10,10 @@ is hierarchical: reduce-scatter inside pods, all-reduce across pods.
 """
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,28 +30,25 @@ def make_host_mesh(model_parallel: int = 1):
 
 
 def make_serving_mesh(shape, axes=("data", "model")):
-    """A (data, model) serving mesh of any shape, on any jax version.
+    """A (data, model) serving mesh over the first ``prod(shape)`` devices.
 
-    ``jax.make_mesh`` only exists on newer releases; older ones build a
-    ``Mesh`` from an explicit device array.  The sharded serving runtime
-    shards prefused partials over ``"model"`` and request batches over
-    ``"data"``, so this is the mesh constructor the serving tests and
-    benchmarks use (on CPU, force devices first with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
+    The axes are ``Auto``: the sharded serving runtime places its arrays
+    with explicit ``NamedSharding``s and runs ``shard_map`` programs, and
+    slices their outputs eagerly, which ``Explicit`` axes (the
+    ``jax.make_mesh`` default) reject.  The runtime shards prefused
+    partials over ``"model"`` and request batches over ``"data"``; on CPU,
+    force devices first with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
     """
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    import numpy as np
-
-    n = 1
-    for s in shape:
-        n *= int(s)
+    shape, axes = tuple(int(s) for s in shape), tuple(axes)
+    n = math.prod(shape)
     devices = jax.devices()
     if n > len(devices):
-        raise ValueError(f"mesh shape {tuple(shape)} needs {n} devices, "
+        raise ValueError(f"mesh shape {shape} needs {n} devices, "
                          f"have {len(devices)}")
-    return jax.sharding.Mesh(
-        np.asarray(devices[:n]).reshape(tuple(shape)), tuple(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices[:n])
 
 
 def dp_axes(mesh) -> tuple:

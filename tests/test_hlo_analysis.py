@@ -21,8 +21,7 @@ def test_scan_flops_multiplied_by_trip_count():
     costs = HloAnalyzer(compiled.as_text()).analyze()
     want = 2.0 * b * d * d * n_iter
     assert costs.flops == pytest.approx(want, rel=0.05)
-    # XLA's own cost_analysis undercounts by ~n_iter (the bug we fix);
-    # its return shape is version-dependent (list-of-dict vs dict).
+    # XLA's own cost_analysis undercounts by ~n_iter (the bug we fix).
     xla_flops = xla_cost_analysis(compiled)["flops"]
     assert xla_flops < want / 2
 
@@ -32,16 +31,11 @@ def test_xla_cost_analysis_normalizes_shapes():
         def cost_analysis(self):
             return None
 
-    class ReturnsList:           # jax ≤ 0.4.x: one dict per partition
-        def cost_analysis(self):
-            return [{"flops": 2.0}]
-
-    class ReturnsDict:           # newer jax
+    class ReturnsDict:
         def cost_analysis(self):
             return {"flops": 3.0}
 
     assert xla_cost_analysis(ReturnsNone()) == {}
-    assert xla_cost_analysis(ReturnsList()) == {"flops": 2.0}
     assert xla_cost_analysis(ReturnsDict()) == {"flops": 3.0}
 
 

@@ -70,6 +70,25 @@ def test_fused_star_gather_linear(n, l, rows):
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
+def test_fused_star_gather_chunks_past_one_call(monkeypatch):
+    """Batches longer than one call's SMEM-resident pointers map over
+    chunks (and a ragged tail) with the same result."""
+    from repro.kernels.fused_star_gather import kernel
+
+    monkeypatch.setattr(kernel, "SMEM_POINTER_BYTES", 64 * 8 * 8)
+    assert kernel.max_rows_per_call(3) == 64
+    rng = np.random.default_rng(7)
+    rows, n, l = (11, 6, 9), 150, 3
+    tables = [jnp.asarray(rng.normal(size=(r, l)).astype(np.float32))
+              for r in rows]
+    ptrs = jnp.asarray(
+        np.stack([rng.integers(0, r, size=n) for r in rows]).astype(np.int32))
+    found = jnp.asarray(rng.integers(0, 2, size=(3, n)).astype(np.int32))
+    got = np.asarray(fused_star_gather(ptrs, found, tables, interpret=True))
+    want = np.asarray(fused_star_gather_ref(ptrs, found, tables))
+    np.testing.assert_array_equal(got, want)
+
+
 def test_fused_star_gather_tree_compare():
     rng = np.random.default_rng(0)
     n, l, rows = 24, 16, (10, 8)

@@ -143,7 +143,13 @@ def _oracle(cat: Catalog, model: LinearOperator, *, group: bool = True):
     cnt = np.maximum(count, 1.0)
     out = {"pred": sums["pred"], "pmean": sums["pmean"] / cnt[:, None],
            "v": sums["v"] / cnt, "n": count}
-    if not group:
+    if group:
+        # The engine packs groups as sorted live codes with a zero tail, so
+        # a group value no live row carries leaves no slot behind.
+        present = np.nonzero(count)[0]
+        out = {k: np.concatenate([v[present], np.zeros_like(v)])[:G]
+               for k, v in out.items()}
+    else:
         out = {k: v[0] for k, v in out.items()}
     return out
 

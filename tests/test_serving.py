@@ -21,7 +21,7 @@ from repro.core.query import (
     plan_serving_backend,
     requests_from_rows,
 )
-from repro.core.query.planner import resolve_serve_backend
+from repro.core.query.planner import plan_fact_backend, resolve_serve_backend
 from repro.data import QUERY_IR, generate_ssb, predictive_query_names, ssb_catalog
 
 PRED_NAMES = predictive_query_names()
@@ -104,9 +104,7 @@ def test_one_plan_serves_ragged_batches_without_recompile(catalog, plans):
         out = runtime.serve(_random_requests(q, catalog, n, rng))
         assert out.shape == (n, runtime.out_width)
     assert runtime.num_compiles == len(BUCKETS)
-    cache = runtime.jit_cache_size()
-    if cache is not None:
-        assert cache == len(BUCKETS)
+    assert runtime.jit_cache_size() == len(BUCKETS)
     # A second ragged sweep plus oversized (chunked) batches: still no
     # recompilation beyond the fixed bucket set.
     for n in sizes + [129, 300, 1000]:
@@ -260,3 +258,17 @@ def test_plan_serving_backend_rules():
     assert resolve_serve_backend("pallas", "nonfused", linear) == "jnp"
     assert resolve_serve_backend("pallas", "nonfused", tree) == "pallas"
     assert resolve_serve_backend("jnp", "fused", linear) == "jnp"
+
+
+def test_plan_fact_backend_keeps_gather_kernel_off_large_facts():
+    """run() scores the whole fact axis: fused_star_gather takes it only
+    when one call's SMEM holds the pointers, and the plan says why not."""
+    from repro.kernels.fused_star_gather.kernel import max_rows_per_call
+
+    rows = max_rows_per_call(3)
+    assert plan_fact_backend("pallas", "fused", 3, rows) == ("pallas", "")
+    backend, why = plan_fact_backend("pallas", "fused", 3, 6_000_000)
+    assert backend == "jnp" and "SMEM" in why
+    # tree_predict streams row blocks, and jnp stays jnp.
+    assert plan_fact_backend("pallas", "nonfused", 3, 6_000_000)[0] == "pallas"
+    assert plan_fact_backend("jnp", "fused", 3, 6_000_000) == ("jnp", "")
