@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import telemetry
 from ..fusion.operators import DecisionTreeGEMM
 from ..fusion.pipeline import (PrefusedStar, extend_prefused, predict_fused,
                                predict_fused_kernel, predict_fused_matmul,
@@ -145,14 +146,15 @@ class CompiledQuery:
         ungrouped count/min/max come back bit-exact vs the in-core path
         (see :mod:`repro.core.query.streaming`).
         """
-        if self._stream is not None:
-            out = dict(self._stream.run())
-        else:
-            out = dict(self._run(self._state))
-        if self.group_codes is not None:
-            out["groups"] = self.group_codes
-        out["rows"] = self._rows
-        return out
+        with telemetry.span("query.dispatch"):
+            if self._stream is not None:
+                out = dict(self._stream.run())
+            else:
+                out = dict(self._run(self._state))
+            if self.group_codes is not None:
+                out["groups"] = self.group_codes
+            out["rows"] = self._rows
+            return out
 
     def predictions(self) -> jnp.ndarray:
         """The (fact_capacity, l) prediction matrix (model queries only)."""
@@ -703,6 +705,7 @@ def _program_state(state: Dict) -> Dict:
     return {k: v for k, v in state.items() if k != "sharded"}
 
 
+@telemetry.span("plan.build")
 def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
                   backend: str = "auto", join_backend: str = "auto",
                   agg_backend: str = "auto", serve_backend: str = "auto",
@@ -809,21 +812,22 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
     # query, so refresh-by-recompile re-runs the rewrite from scratch.
     rewrite_trail: Tuple[str, ...] = ()
     if rewrite == "on" and not holds_tracers(cat0, q):
-        rw = rewrite_query(cat0, q)
-        if rw.changed:
-            def _cost(qq):
-                return estimate_query_cost(
-                    qq.model, cat0[qq.fact].capacity,
-                    [cat0[a.table].capacity for a in qq.arms],
-                    out_width=qq.model.l if qq.model is not None else 1,
-                    batches_per_update=batches_per_update)
-            cost_orig, cost_rw = _cost(q), _cost(rw.query)
-            if cost_rw <= cost_orig:
-                q = rw.query
-                rewrite_trail = rw.trail
-            else:
-                rewrite_trail = (
-                    f"rejected: cost {cost_rw:.3g} > {cost_orig:.3g}",)
+        with telemetry.span("plan.rewrite"):
+            rw = rewrite_query(cat0, q)
+            if rw.changed:
+                def _cost(qq):
+                    return estimate_query_cost(
+                        qq.model, cat0[qq.fact].capacity,
+                        [cat0[a.table].capacity for a in qq.arms],
+                        out_width=qq.model.l if qq.model is not None else 1,
+                        batches_per_update=batches_per_update)
+                cost_orig, cost_rw = _cost(q), _cost(rw.query)
+                if cost_rw <= cost_orig:
+                    q = rw.query
+                    rewrite_trail = rw.trail
+                else:
+                    rewrite_trail = (
+                        f"rejected: cost {cost_rw:.3g} > {cost_orig:.3g}",)
     # Pool sharing engages only on the plain single-device path against the
     # pool's own catalog: select-compaction rebinds the fact to a local
     # table, mesh placement commits arrays to devices, and tracer-holding
@@ -870,9 +874,10 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
         catalog = {**catalog, **{c.table.name: c.table
                                  for c in chains if c is not None}}
         q = dataclasses.replace(q, arms=tuple(flat_arm(a) for a in q.arms))
-    star, valid, indices, arm_refs = _resolve_star(
-        catalog, q, pool=pool if use_pool else None, chains=chains,
-        chain_keys=chain_keys)
+    with telemetry.span("plan.star"):
+        star, valid, indices, arm_refs = _resolve_star(
+            catalog, q, pool=pool if use_pool else None, chains=chains,
+            chain_keys=chain_keys)
     fact = star.fact
     rows = jnp.sum(valid.astype(jnp.int32))
     # Offline compilation measures selectivity from the data; when a caller
@@ -884,17 +889,20 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
     except jax.errors.ConcretizationTypeError:
         sel = 1.0
 
-    # Group codes resolve before planning so ``num_groups="auto"`` can size
-    # the group dimension from the measured code domain (the codes are
-    # concrete on the offline path) and feed the planner the real G.
-    codes = None
-    n_live = None
+    # Group codes and dense ids resolve before planning so
+    # ``num_groups="auto"`` can size the group dimension from the measured
+    # code domain (the codes are concrete on the offline path) and feed the
+    # planner the real G.
+    uniq = gid = None
     if q.group_keys:
-        cols, bounds = _group_columns(catalog, q, star, chains)
-        codes = composite_code(cols, bounds, valid)
-        if q.num_groups == "auto":
-            n_live = auto_num_groups(codes)
-            q = dataclasses.replace(q, num_groups=n_live)
+        with telemetry.span("plan.groups"):
+            cols, bounds = _group_columns(catalog, q, star, chains)
+            codes = composite_code(cols, bounds, valid)
+            n_live = None
+            if q.num_groups == "auto":
+                n_live = auto_num_groups(codes)
+                q = dataclasses.replace(q, num_groups=n_live)
+            uniq, gid = groupby_codes(codes, q.num_groups, n_live=n_live)
     elif q.num_groups == "auto":
         q = dataclasses.replace(
             q, num_groups=PredictiveQuery.__dataclass_fields__[
@@ -999,10 +1007,6 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
             prefused = PrefusedStar(parts, h)
         else:
             prefused = prefuse(star, q.model)
-
-    uniq = gid = None
-    if q.group_keys:
-        uniq, gid = groupby_codes(codes, q.num_groups, n_live=n_live)
 
     reduce_fn = (matmul_aggregate if agg_backend == "matmul"
                  else segment_aggregate)

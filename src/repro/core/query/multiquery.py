@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import telemetry
 from ..fusion.operators import DecisionTreeGEMM, LinearOperator
 from ..fusion.pipeline import _feature_slices, prefuse_dims, prefuse_rows
 from ..laq.catalog import Catalog, CatalogHistoryError, changed_spans
@@ -284,8 +285,10 @@ class ArtifactPool:
         entry = self._entries.get(key)
         if entry is None:
             self.misses += 1
+            with telemetry.span("pool." + kind, tables=",".join(tables)):
+                value = build()
             entry = _PoolEntry(
-                key=key, kind=kind, value=build(),
+                key=key, kind=kind, value=value,
                 versions={n: self.catalog.version(n) for n in tables},
                 spec=dict(spec))
             self._entries[key] = entry

@@ -49,6 +49,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from ... import telemetry
 from ..laq.catalog import Catalog
 from ..laq.selection import Pred
 from ..laq.table import Table
@@ -426,9 +427,13 @@ class QueryBuilder:
     def run(self, **overrides) -> Dict[str, jnp.ndarray]:
         """Execute the whole-query aggregate program.
 
-        Returns the named aggregates (+ ``"groups"``/``"rows"``).
+        Returns the named aggregates (+ ``"groups"``/``"rows"``).  The call
+        is one ``query`` span (see :mod:`repro.telemetry`): the plan lookup,
+        the plan's build on a miss, and the dispatch; not the wait for the
+        answer.
         """
-        return self.compile(**overrides).run()
+        with telemetry.span("query"):
+            return self.compile(**overrides).run()
 
     def rows(self, batch, **overrides) -> jnp.ndarray:
         """Row predictions for a batch of fact row ids (serving-by-row)."""

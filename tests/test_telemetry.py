@@ -1,0 +1,165 @@
+"""Spans and compile counters of the query path (``repro.telemetry``): the
+span tree of a query's first and later calls, the compile events credited
+to it, the bounded ring, and the spans in a recorded profile."""
+import glob
+import os
+
+import jax
+import pytest
+
+from repro import telemetry
+from repro.data import QUERY_IR, generate_ssb, ssb_session
+
+# A fact size no other test uses, so that the first call compiles its
+# programs here whatever ran before in this process.
+SCALE = 0.0007
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def session(no_persistent_cache):
+    return ssb_session(generate_ssb(sf=1, scale=SCALE, seed=13))
+
+
+def _call(sess, name):
+    """Run query ``name`` once; returns the spans of its request."""
+    telemetry.reset()
+    jax.block_until_ready(sess.bind(QUERY_IR[name]()).run())
+    return telemetry.spans()
+
+
+@pytest.fixture(scope="module")
+def calls(session):
+    """Q2.1's first and second call, then Q2.2, whose joins Q2.1 built."""
+    return {"first": _call(session, "Q2.1"), "second": _call(session, "Q2.1"),
+            "reuse": _call(session, "Q2.2")}
+
+
+def _tree(spans):
+    """(root, {span id: [children in start order]})."""
+    roots = [s for s in spans if s.parent is None]
+    assert len(roots) == 1
+    kids = {}
+    for s in sorted(spans, key=lambda s: s.start):
+        if s.parent is not None:
+            kids.setdefault(s.parent, []).append(s)
+    return roots[0], kids
+
+
+def _subtree(span, kids):
+    out = [span]
+    for k in kids.get(span.id, []):
+        out += _subtree(k, kids)
+    return out
+
+
+def test_first_call_builds_then_dispatches(calls):
+    spans = calls["first"]
+    root, kids = _tree(spans)
+    assert root.name == "query"
+    assert [s.name for s in kids[root.id]] == ["plan.build", "query.dispatch"]
+    build = kids[root.id][0]
+    below = [s.name for s in kids[build.id]]
+    assert "plan.star" in below and "plan.groups" in below
+    assert below.index("plan.star") < below.index("plan.groups")
+    star = next(s for s in kids[build.id] if s.name == "plan.star")
+    assert {"pool.pkindex", "pool.join", "pool.dmask"} <= {
+        s.name for s in kids[star.id]}
+    assert {s.request for s in spans} == {root.id}
+
+
+def test_second_call_only_dispatches(calls):
+    root, kids = _tree(calls["second"])
+    assert root.name == "query"
+    assert [s.name for s in kids[root.id]] == ["query.dispatch"]
+    assert len(calls["second"]) == 2
+
+
+@pytest.mark.parametrize("call", ["first", "second", "reuse"])
+def test_children_lie_inside_their_parents(calls, call):
+    spans = calls[call]
+    by_id = {s.id: s for s in spans}
+    _, kids = _tree(spans)
+    for s in spans:
+        assert s.start <= s.end
+        if s.parent is not None:
+            p = by_id[s.parent]
+            assert p.start <= s.start and s.end <= p.end
+        covered = sum(k.end - k.start for k in kids.get(s.id, []))
+        assert (s.end - s.start) - covered >= 0     # self time
+
+
+def test_compile_events_are_credited_to_the_first_call(calls):
+    root, kids = _tree(calls["first"])
+    build, dispatch = kids[root.id]
+    assert sum(s.programs for s in _subtree(build, kids)) > 0
+    assert dispatch.programs > 0 and dispatch.compile_s > 0
+    assert sum(s.programs for s in calls["second"]) == 0
+    assert sum(s.compile_s for s in calls["second"]) == 0
+
+
+def test_totals_add_up_the_records(calls, session):
+    spans = _call(session, "Q2.1")
+    tot = telemetry.totals()
+    assert {n: t.count for n, t in tot.items()} == {
+        "query": 1, "query.dispatch": 1}
+    for s in spans:
+        assert tot[s.name].seconds == pytest.approx(s.end - s.start)
+        assert tot[s.name].max == tot[s.name].seconds
+
+
+def test_a_reused_join_builds_no_pool_join(calls):
+    names = [s.name for s in calls["reuse"]]
+    assert "plan.build" in names and "pool.dmask" in names
+    assert "pool.join" not in names and "pool.pkindex" not in names
+
+
+def test_the_ring_is_bounded():
+    telemetry.reset()
+    try:
+        for _ in range(telemetry.RING_SIZE + 10):
+            with telemetry.span("tick"):
+                pass
+        spans = telemetry.spans()
+        assert len(spans) == telemetry.RING_SIZE
+        assert spans[0].id < spans[-1].id
+        assert telemetry.totals()["tick"].count == telemetry.RING_SIZE + 10
+    finally:
+        telemetry.reset()
+
+
+def test_span_records_an_exception_and_reraises():
+    telemetry.reset()
+    with pytest.raises(KeyError):
+        with telemetry.span("outer"):
+            with telemetry.span("inner"):
+                raise KeyError("x")
+    inner, outer = telemetry.spans()
+    assert (inner.name, outer.name) == ("inner", "outer")
+    assert inner.parent == outer.id and inner.request == outer.id
+    with telemetry.span("next"):
+        pass
+    assert telemetry.spans()[-1].parent is None     # the stack unwound
+
+
+def test_spans_land_in_a_recorded_profile(session, calls, tmp_path):
+    q = session.bind(QUERY_IR["Q2.1"]())
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(q.run())
+    paths = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1
+    pd = jax.profiler.ProfileData.from_file(paths[0])
+    names = {e.name for plane in pd.planes if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"repro.query", "repro.query.dispatch"} <= names
