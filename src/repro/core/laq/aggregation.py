@@ -14,6 +14,8 @@
   (quasi-static), then reduce values — either with ``segment_sum`` or with
   the Fig. 4 one-hot matmul.  Both accept (n,) scalars and (n, l) prediction
   matrices, so a fused model head aggregates with the same machinery.
+  ``groupby_rows`` resolves the same ids for the live rows only, so a
+  selective plan reduces a short row list instead of the whole fact.
 
 All functions are padding-aware: rows whose group code is PAD_GROUP are
 dropped from every aggregate.
@@ -157,12 +159,40 @@ def groupby_reduce(codes: jnp.ndarray, values: Sequence[jnp.ndarray],
 # --------------------------------------------------------------------------
 def _live_code_count(codes: jnp.ndarray) -> "int | None":
     """Distinct live (non-PAD_GROUP) codes, or None when codes are traced."""
+    concrete = _concrete_codes(codes)
+    if concrete is None:
+        return None
+    return int(np.unique(concrete[concrete != int(PAD_GROUP)]).size)
+
+
+def _concrete_codes(codes: jnp.ndarray) -> "np.ndarray | None":
     try:
-        concrete = np.asarray(codes)
+        return np.asarray(codes)
     except (jax.errors.ConcretizationTypeError,
             jax.errors.TracerArrayConversionError):
         return None
-    return int(np.unique(concrete[concrete != int(PAD_GROUP)]).size)
+
+
+def _check_group_count(concrete, num_groups: int, n_live: "int | None"):
+    if n_live is None and concrete is not None:
+        n_live = int(np.unique(concrete[concrete != int(PAD_GROUP)]).size)
+    if n_live is not None and n_live > num_groups:
+        raise ValueError(
+            f"group-by overflow: {n_live} distinct live group codes "
+            f"exceed num_groups={num_groups}; the excess groups would "
+            "silently vanish from every aggregate. Raise num_groups "
+            f"(>= {n_live}) or coarsen the group keys.")
+
+
+def _resolve_host(concrete: np.ndarray, num_groups: int
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    u = np.unique(concrete)[:num_groups]
+    uniq = np.full((num_groups,), int(PAD_GROUP), dtype=concrete.dtype)
+    uniq[:u.size] = u
+    gid = np.searchsorted(uniq, concrete).astype(np.int32)
+    gid = np.where(concrete != int(PAD_GROUP),
+                   np.minimum(gid, num_groups), num_groups)
+    return uniq, gid.astype(np.int32)
 
 
 def groupby_codes(codes: jnp.ndarray, num_groups: int, *,
@@ -187,32 +217,57 @@ def groupby_codes(codes: jnp.ndarray, num_groups: int, *,
     a multi-query compile sweep.  Both paths are bit-identical (same sort
     order, same 'left' searchsorted, same overflow clamp).
     """
-    try:
-        concrete = np.asarray(codes)
-    except (jax.errors.ConcretizationTypeError,
-            jax.errors.TracerArrayConversionError):
-        concrete = None
-    if n_live is None and concrete is not None:
-        n_live = int(np.unique(concrete[concrete != int(PAD_GROUP)]).size)
-    if n_live is not None and n_live > num_groups:
-        raise ValueError(
-            f"group-by overflow: {n_live} distinct live group codes "
-            f"exceed num_groups={num_groups}; the excess groups would "
-            "silently vanish from every aggregate. Raise num_groups "
-            f"(>= {n_live}) or coarsen the group keys.")
+    concrete = _concrete_codes(codes)
+    _check_group_count(concrete, num_groups, n_live)
     if concrete is not None:
-        u = np.unique(concrete)[:num_groups]
-        uniq = np.full((num_groups,), int(PAD_GROUP), dtype=concrete.dtype)
-        uniq[:u.size] = u
-        gid = np.searchsorted(uniq, concrete).astype(np.int32)
-        gid = np.where(concrete != int(PAD_GROUP),
-                       np.minimum(gid, num_groups), num_groups)
-        return jnp.asarray(uniq), jnp.asarray(gid.astype(np.int32))
+        uniq, gid = _resolve_host(concrete, num_groups)
+        return jnp.asarray(uniq), jnp.asarray(gid)
     uniq = jnp.unique(codes, size=num_groups, fill_value=PAD_GROUP)
     gid = jnp.searchsorted(uniq, codes).astype(jnp.int32)
     gid = jnp.where(codes != PAD_GROUP,
                     jnp.minimum(gid, num_groups), num_groups)
     return uniq, gid
+
+
+def compact_capacity(n_rows: int, max_rows: int) -> int:
+    """Static length of a live-row list: ``n_rows`` and a sixteenth more,
+    rounded up to a multiple of 1024, at most ``max_rows`` (the fact's
+    capacity).  The headroom lets appends of up to a sixteenth more live
+    rows keep the shape; a power-of-two length would pad up to twice the
+    rows, and every padded row costs a gather and a scatter like a live
+    one."""
+    want = max(int(n_rows) + int(n_rows) // 16, 1)
+    return min(-(-want // 1024) * 1024, int(max_rows))
+
+
+def groupby_rows(codes: jnp.ndarray, num_groups: int, capacity: int, *,
+                 n_live: "int | None" = None
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """:func:`groupby_codes` kept to the live rows only (concrete codes).
+
+    Returns ``(uniq, rows, gid)``: the sorted unique codes, the ascending
+    row ids whose code is live, and their dense group ids, both padded to
+    ``capacity`` — padding points at row 0 with the overflow id
+    ``num_groups``, which every aggregation backend drops.  Reducing
+    values gathered at ``rows`` over ``gid`` gives the groups of reducing
+    every row over ``groupby_codes``' ids; a sequential segment sum gives
+    them bit for bit, since each slot receives the same rows in the same
+    order.  More live rows than ``capacity`` raise.
+    """
+    concrete = _concrete_codes(codes)
+    if concrete is None:
+        raise ValueError("groupby_rows needs concrete group codes")
+    _check_group_count(concrete, num_groups, n_live)
+    uniq, gid = _resolve_host(concrete, num_groups)
+    live = np.flatnonzero(gid != num_groups)
+    if live.size > capacity:
+        raise ValueError(f"{live.size} live rows exceed the row-list "
+                         f"capacity {capacity}")
+    rows = np.zeros((capacity,), np.int32)
+    rows[:live.size] = live
+    gid_live = np.full((capacity,), num_groups, np.int32)
+    gid_live[:live.size] = gid[live]
+    return jnp.asarray(uniq), jnp.asarray(rows), jnp.asarray(gid_live)
 
 
 def auto_num_groups(codes: jnp.ndarray) -> int:

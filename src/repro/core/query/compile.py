@@ -15,7 +15,8 @@ Offline (quasi-static, runs once per (query, catalog version set)):
 Online (the single jitted program): Σⱼ Iⱼ Pⱼ gathers (+ ``== h`` for trees),
 value expressions, and the group-by reduction composed directly on the fused
 prediction output — no intermediate table ever materializes on the fused
-path.
+path.  A selective model-free group-by reduces only its live rows, gathered
+from the resident fact at a row list that step 4 keeps.
 
 Incremental maintenance: every quasi-static array the online programs read
 (matrices, pointers, masks, partials, group ids) is threaded through the
@@ -25,8 +26,8 @@ a retrace on every append.  :meth:`CompiledQuery.refresh` applies pending
 :class:`~repro.core.laq.catalog.Catalog` deltas to that state (sorted-merge
 ``PKIndex.extend``, delta ``prefuse_rows``, mask scatters): same shapes ⇒
 the swapped state hits the same jit cache, no retrace; capacity growth (or
-select-compaction / group overflow) falls back to a recompile with a named
-``explain()`` reason.
+select-compaction / group overflow / live rows outgrowing the compacted row
+list) falls back to a recompile with a named ``explain()`` reason.
 """
 from __future__ import annotations
 
@@ -46,7 +47,7 @@ from ..fusion.pipeline import (PrefusedStar, extend_prefused, predict_fused,
                                predict_nonfused, predict_nonfused_kernel,
                                predict_nonfused_matmul, prefuse)
 from ..laq.aggregation import (auto_num_groups, composite_code,
-                               groupby_codes, matmul_aggregate,
+                               groupby_codes, groupby_rows, matmul_aggregate,
                                segment_aggregate, segment_reduce)
 from ..laq.catalog import Catalog, CatalogHistoryError, changed_spans
 from ..laq.join import FactoredJoin, PKIndex, pk_index
@@ -60,8 +61,8 @@ from .ir import (AGG_OPS, PREDICTION, Aggregate, ArmSpec, PredictiveQuery,
 from .multiquery import holds_tracers
 from .planner import (QueryPlan, effective_serve_backend,
                       estimate_query_cost, place_tables, plan_fact_backend,
-                      plan_chain_materialization, plan_query, plan_streaming,
-                      resolve_mesh_serve_backend)
+                      plan_chain_materialization, plan_compaction,
+                      plan_query, plan_streaming, resolve_mesh_serve_backend)
 from .rewrite import _FILTER_FNS, rewrite_query
 from .snowflake import (CollapsedChain, chain_dirty_heads, chain_tables,
                         flat_arm, link_parents, participating_tables,
@@ -91,7 +92,6 @@ class CompiledQuery:
     prefused: Optional[PrefusedStar]
     selectivity: float              # measured fraction of surviving fact rows
     group_codes: Optional[jnp.ndarray]   # sorted unique composite codes
-    _gid: Optional[jnp.ndarray]
     _rows: jnp.ndarray                   # surviving-row count
     _run: callable
     _predict: Optional[callable]
@@ -257,6 +257,8 @@ class CompiledQuery:
         except _GroupOverflow:
             return self._recompile("group-overflow: live codes exceed the "
                                    "compiled num_groups")
+        except _CompactOverflow as e:
+            return self._recompile(f"compact-overflow: {e}")
 
     def _note(self, line: str) -> str:
         if not self._refresh_notes:
@@ -432,24 +434,34 @@ class CompiledQuery:
         """Shared delta-refresh tail: group codes, counts, state pytree."""
         q = self.query
         cat = self.catalog
-        codes = uniq = gid = None
+        rows = jnp.sum(valid.astype(jnp.int32))
+        uniq = gid = live = gid_live = None
         if q.group_keys:
             cols, bounds = _group_columns(cat, q, star, self._chains)
             codes = composite_code(cols, bounds, valid)
+            # A compacted plan keeps its row list's length, so the delta
+            # path stays zero-retrace while the live rows fit it.
+            cap = (None if self._state["rows"] is None
+                   else int(self._state["rows"].shape[0]))
+            if cap is not None and int(rows) > cap:
+                raise _CompactOverflow(f"{int(rows)} live rows exceed the "
+                                       f"compiled row list of {cap}")
             try:
-                uniq, gid = groupby_codes(codes, q.num_groups)
+                if cap is None:
+                    uniq, gid = groupby_codes(codes, q.num_groups)
+                else:
+                    uniq, live, gid_live = groupby_rows(
+                        codes, q.num_groups, cap)
             except ValueError as e:
                 raise _GroupOverflow(str(e)) from e
 
-        rows = jnp.sum(valid.astype(jnp.int32))
         n_fact = _static_int(star.fact.nvalid, star.fact.capacity)
         self.star = star
         self.prefused = prefused
         self.group_codes = uniq
-        self._gid = gid
         self._rows = rows
         self.selectivity = float(rows) / max(n_fact, 1)
-        state = _query_state(star, prefused, gid)
+        state = _query_state(star, prefused, gid, live, gid_live)
         if self._sp is not None:
             tables = (list(prefused.partials) if self.backend == "fused"
                       else [project_columns(d.dim.matrix, d.dim.columns,
@@ -471,6 +483,10 @@ class CompiledQuery:
 
 class _GroupOverflow(ValueError):
     """Internal: live group codes outgrew the compiled num_groups."""
+
+
+class _CompactOverflow(Exception):
+    """Internal: live rows outgrew a compacted plan's row list."""
 
 
 def _static_int(x, default: int) -> int:
@@ -655,12 +671,16 @@ def _check_aggregates(q: PredictiveQuery):
 # Quasi-static state as a pytree (the jitted programs' data argument)
 # --------------------------------------------------------------------------
 def _query_state(star: StarJoin, prefused: Optional[PrefusedStar],
-                 gid: Optional[jnp.ndarray]) -> Dict:
+                 gid: Optional[jnp.ndarray],
+                 rows: Optional[jnp.ndarray] = None,
+                 gid_live: Optional[jnp.ndarray] = None) -> Dict:
     """Every array the online programs read, as one swappable pytree.
 
     ``refresh`` replaces leaves with same-shape updates; because these are
     jit *arguments* (not closure constants), the swapped state re-dispatches
-    into the already-compiled executables.
+    into the already-compiled executables.  A grouped plan carries either
+    ``gid`` (one id per fact row, the masked lowering) or ``rows`` and
+    ``gid_live`` (its live rows and their ids, the compacted lowering).
     """
     return {
         "fact_matrix": star.fact.matrix,
@@ -672,6 +692,8 @@ def _query_state(star: StarJoin, prefused: Optional[PrefusedStar],
                      if prefused is not None else None),
         "h": prefused.h if prefused is not None else None,
         "gid": gid,
+        "rows": rows,
+        "gid_live": gid_live,
         "sharded": None,
     }
 
@@ -756,6 +778,12 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
     grouped aggregates and ungrouped count/min/max — see
     :mod:`repro.core.query.streaming`.  The serving paths
     (``predict_rows``) are request-batched and unaffected.
+
+    A model-free group-by whose measured selectivity is at most the
+    planner's ``COMPACT_AGG_MAX_SELECTIVITY`` keeps its live rows and their
+    group ids offline, and its program aggregates over those rows alone,
+    read from the resident fact on every call (``agg=compact`` in
+    ``explain()``; :func:`~.planner.plan_compaction`).
 
     ``select_capacity`` applies the fact predicates by ``mask_select``
     compaction (§2.2) *before* the joins: surviving rows are packed into a
@@ -851,7 +879,7 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
     # unchanged star pipeline, bit-exact with materializing each chain.
     chains: Tuple = ()
     chain_keys: Tuple = ()
-    chain_notes = []
+    notes = []
     if any(a.links for a in q.arms):
         ccs, ckeys = [], []
         for arm in q.arms:
@@ -863,7 +891,7 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
                 virtual_name(arm),
                 [catalog[p].capacity for p in link_parents(arm)],
                 strategy=chain_strategy)
-            chain_notes.append(note)
+            notes.append(note)
             if use_pool:
                 cc, ckey = pool.acquire_chain(arm, keep_hops=k)
             else:
@@ -884,16 +912,30 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
     # traces compile_query itself (whole pipeline under one outer jit), the
     # counts are abstract — plan with static shapes and selectivity 1.
     n_fact = _static_int(fact.nvalid, fact.capacity)
+    traced = isinstance(rows, jax.core.Tracer) or holds_tracers(cat0,
+                                                                source_q)
     try:
         sel = float(rows) / max(n_fact, 1)
     except jax.errors.ConcretizationTypeError:
         sel = 1.0
+    out_width = q.model.l if q.model is not None else 1
+
+    # Out-of-core decision: fact working-set bytes vs the device budget
+    # (planner), or a caller-pinned chunk size.
+    stream_rows = stream_reason = None
+    if stream_chunk_rows is not None or memory_budget_bytes is not None:
+        row_bytes = _fact_row_bytes(fact, q, len(star.dims), out_width)
+        stream_rows, stream_reason = plan_streaming(
+            stream_chunk_rows, fact.capacity, row_bytes,
+            memory_budget_bytes)
 
     # Group codes and dense ids resolve before planning so
     # ``num_groups="auto"`` can size the group dimension from the measured
     # code domain (the codes are concrete on the offline path) and feed the
-    # planner the real G.
-    uniq = gid = None
+    # planner the real G.  A selective model-free plan keeps only its live
+    # rows and their ids: its program aggregates over those rows alone.
+    uniq = gid = live = gid_live = None
+    compact_cap = None
     if q.group_keys:
         with telemetry.span("plan.groups"):
             cols, bounds = _group_columns(catalog, q, star, chains)
@@ -902,23 +944,35 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
             if q.num_groups == "auto":
                 n_live = auto_num_groups(codes)
                 q = dataclasses.replace(q, num_groups=n_live)
-            uniq, gid = groupby_codes(codes, q.num_groups, n_live=n_live)
+            blocker = ("model head" if q.model is not None
+                       else "streamed" if stream_rows is not None
+                       else "traced" if traced else None)
+            n_rows = None if traced else int(rows)
+            compact_cap, compact_note = plan_compaction(
+                sel, n_rows, fact.capacity, blocker)
+            notes.append(compact_note)
+            if compact_cap is None:
+                uniq, gid = groupby_codes(codes, q.num_groups, n_live=n_live)
+            else:
+                with telemetry.span("plan.compact", rows=n_rows,
+                                    capacity=compact_cap):
+                    uniq, live, gid_live = groupby_rows(
+                        codes, q.num_groups, compact_cap, n_live=n_live)
     elif q.num_groups == "auto":
         q = dataclasses.replace(
             q, num_groups=PredictiveQuery.__dataclass_fields__[
                 "num_groups"].default)
 
-    out_width = q.model.l if q.model is not None else 1
-    # The planner's selectivity term models mask_select compaction (§2.2):
-    # online shapes only actually shrink when ``select_capacity`` compacted
-    # the fact table (already reflected in n_fact).  The default lowering
-    # masks without compacting, so its online cost stays at full capacity —
-    # feeding the measured selectivity in would optimize a plan shape that
-    # is not the one being executed.
+    # The planner's selectivity term is the share of fact rows the online
+    # program touches: the compacted row list for a compacted plan, the
+    # whole (possibly ``select_capacity``-compacted, §2.2) fact otherwise —
+    # the masked lowering's online cost stays at full capacity.
+    online_sel = (1.0 if compact_cap is None
+                  else min(compact_cap / max(n_fact, 1), 1.0))
     plan = plan_query(q.model, n_fact,
                       [_static_int(d.dim.nvalid, d.dim.capacity)
                        for d in star.dims],
-                      selectivity=1.0,
+                      selectivity=online_sel,
                       num_groups=q.num_groups if q.group_keys else 0,
                       out_width=out_width,
                       agg_ops=tuple(a.op for a in q.aggregates),
@@ -926,44 +980,36 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
                       memory_budget_bytes=memory_budget_bytes,
                       sharing=sharing)
     if rewrite_trail:
-        chain_notes.insert(0, "rewrite=[" + "; ".join(rewrite_trail) + "]")
-    if chain_notes:
+        notes.insert(0, "rewrite=[" + "; ".join(rewrite_trail) + "]")
+    if notes:
         plan = dataclasses.replace(
-            plan, reason="; ".join([plan.reason, *chain_notes]))
+            plan, reason="; ".join([plan.reason, *notes]))
     backend = plan.backend if backend == "auto" else backend
     join_backend = plan.join_backend if join_backend == "auto" else join_backend
     agg_backend = ((plan.agg.backend if plan.agg else "segment")
                    if agg_backend == "auto" else agg_backend)
 
-    # Out-of-core decision: fact working-set bytes vs the device budget
-    # (planner), or a caller-pinned chunk size.  Streaming runs the fused
-    # gather/segment program per chunk — the one lowering whose per-row
-    # bits are independent of chunking — so explicit conflicting backend
-    # overrides are rejected rather than silently un-streamed.
-    stream_rows = None
-    if stream_chunk_rows is not None or memory_budget_bytes is not None:
-        row_bytes = _fact_row_bytes(fact, q, len(star.dims), out_width)
-        stream_rows, stream_reason = plan_streaming(
-            stream_chunk_rows, fact.capacity, row_bytes,
-            memory_budget_bytes)
-        if (stream_rows is not None and stream_chunk_rows is None
-                and q.model is not None and backend == "nonfused"
-                and plan.fusion is not None
-                and memory_budget_bytes is not None
-                and plan.fusion.prefused_bytes > memory_budget_bytes):
-            # The budget already ruled out resident prefused partials
-            # (plan_fusion's older contract) — chunking the fact cannot
-            # shrink the dimension side, so the budget-driven path defers
-            # to that choice.  A merely amortization-driven nonfused pick
-            # does NOT defer: out-of-core has no nonfused lowering, and
-            # prefusing is the price of exceeding memory.  An explicit
-            # chunk size always streams.
-            stream_rows = None
-            stream_reason = "stream=off (budget forces nonfused prefuse)"
-        if stream_reason:
-            plan = dataclasses.replace(
-                plan, stream_chunk_rows=stream_rows,
-                reason=f"{plan.reason}; {stream_reason}")
+    # Streaming runs the fused gather/segment program per chunk — the one
+    # lowering whose per-row bits are independent of chunking — so explicit
+    # conflicting backend overrides are rejected rather than silently
+    # un-streamed.
+    if (stream_rows is not None and stream_chunk_rows is None
+            and q.model is not None and backend == "nonfused"
+            and plan.fusion is not None
+            and memory_budget_bytes is not None
+            and plan.fusion.prefused_bytes > memory_budget_bytes):
+        # The budget already ruled out resident prefused partials
+        # (plan_fusion's older contract) — chunking the fact cannot shrink
+        # the dimension side, so the budget-driven path defers to that
+        # choice.  A merely amortization-driven nonfused pick does NOT
+        # defer: out-of-core has no nonfused lowering, and prefusing is the
+        # price of exceeding memory.  An explicit chunk size always streams.
+        stream_rows = None
+        stream_reason = "stream=off (budget forces nonfused prefuse)"
+    if stream_reason:
+        plan = dataclasses.replace(
+            plan, stream_chunk_rows=stream_rows,
+            reason=f"{plan.reason}; {stream_reason}")
     if stream_rows is not None:
         for name, val, bad in (("backend", opts["backend"], "nonfused"),
                                ("join_backend", opts["join_backend"],
@@ -976,8 +1022,7 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
                     f"{bad!r}: chunked execution folds partial aggregates "
                     "through the fused gather/segment program (matmul "
                     "lowerings are not bitwise chunk-stable)")
-        if isinstance(rows, jax.core.Tracer) or holds_tracers(cat0,
-                                                              source_q):
+        if traced:
             raise ValueError(
                 "streaming is an offline host-side driver: it cannot run "
                 "under an outer trace (compile without stream_chunk_rows "
@@ -1043,9 +1088,17 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
         return jnp.where(valid_v, vals, 0.0)
 
     def _online(state):
-        fact_v = dataclasses.replace(fact, matrix=state["fact_matrix"])
-        valid_v = state["valid"]
-        gid_v = state["gid"]
+        if state["rows"] is None:
+            fact_v = dataclasses.replace(fact, matrix=state["fact_matrix"])
+            valid_v = state["valid"]
+            gid_v = state["gid"]
+        else:
+            # Compacted: the live rows, gathered from the resident fact on
+            # every call; padding rows carry the dropped overflow id.
+            fact_v = dataclasses.replace(fact, matrix=jnp.take(
+                state["fact_matrix"], state["rows"], axis=0))
+            gid_v = state["gid_live"]
+            valid_v = gid_v != num_groups
         pred = _predictions(state) if model is not None else None
         out = {}
         # One shared count reduction backs every count/mean aggregate.
@@ -1086,7 +1139,7 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
                 out[agg.name] = jnp.sum(vals, axis=0)
         return out
 
-    state = _query_state(star, prefused, gid)
+    state = _query_state(star, prefused, gid, live, gid_live)
     online_jit = jax.jit(_online)
     pred_jit = jax.jit(_predictions)
 
@@ -1137,8 +1190,7 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
     return CompiledQuery(
         query=q, plan=plan, backend=backend, join_backend=join_backend,
         agg_backend=agg_backend, serve_backend=serve_backend, star=star,
-        prefused=prefused, selectivity=sel, group_codes=uniq, _gid=gid,
-        _rows=rows, _run=run_fn, _predict=predict_jit,
+        prefused=prefused, selectivity=sel, group_codes=uniq, _rows=rows, _run=run_fn, _predict=predict_jit,
         _predict_rows=predict_rows_jit, _state=state, catalog=cat0,
         versions={n: cat0.version(n)
                   for n in participating_tables(source_q)},

@@ -830,7 +830,7 @@ def artifact_bytes(plans) -> int:
     for p in plans:
         state = getattr(p, "_state", None)
         if state is not None and "ptrs" in state:      # CompiledQuery
-            for k in ("valid", "gid", "h"):
+            for k in ("valid", "gid", "rows", "gid_live", "h"):
                 add(state.get(k))
             for k in ("ptrs", "founds", "partials"):
                 for a in (state.get(k) or ()):

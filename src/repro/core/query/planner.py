@@ -26,6 +26,7 @@ from ...kernels.fused_star_gather.kernel import max_rows_per_call
 from ...launch.sharding import safe_spec
 from ..fusion.operators import DecisionTreeGEMM
 from ..fusion.planner import FusionDecision, plan_fusion
+from ..laq.aggregation import compact_capacity
 from .ir import Model
 
 # Cost-model thresholds, keyed by ``jax.default_backend()`` with the
@@ -45,6 +46,14 @@ from .ir import Model
 #   replication keeps the online gather collective-free.  CPU-bench
 #   calibrated (bench_sharded_serving: the psum overhead only amortizes once
 #   per-device slices clear the cache-resident regime).
+# * COMPACT_AGG_MAX_SELECTIVITY — a grouped plan aggregates over its
+#   compacted live rows, not the masked fact, at or below this share of
+#   live rows.  Measured on a TPU v5e over SSB's (60M, 10) f32 fact into
+#   8193 slots: the masked segment-sum costs about 8.5 ns a fact row
+#   (512 ms); gathering a row-list entry and scattering it about 35 ns
+#   (148 ms for 4.2M entries, 112 ms of it the gather).  The list holds a
+#   sixteenth more entries than live rows, so compaction wins below
+#   8.5 / (35 * 17 / 16) = 0.23.
 PLANNER_THRESHOLDS = {
     "default": {
         "DENSE_JOIN_ELEMS": 1 << 14,
@@ -55,6 +64,7 @@ PLANNER_THRESHOLDS = {
         # Hops are cached parent-first until the budget runs out —
         # materialize-at-hop-k; a zero/overflowing budget prefuses through.
         "CHAIN_CACHE_BYTES": 1 << 22,
+        "COMPACT_AGG_MAX_SELECTIVITY": 0.2,
     },
     # "tpu": {...}  ← ROADMAP "Planner calibration": re-measure there and
     # fill this row in; every decision point below reads through
@@ -396,6 +406,27 @@ def plan_aggregation(online_rows: float, num_groups: int, out_width: int,
     return AggDecision("segment", matmul + shared, segment + shared,
                        f"G={g}: segment ops ({segment + shared:.0f} flops) "
                        f"beat one-hot matmul ({matmul + shared:.0f} flops)")
+
+
+def plan_compaction(selectivity: float, n_rows: int, fact_capacity: int,
+                    blocker: Optional[str] = None,
+                    platform: Optional[str] = None
+                    ) -> Tuple[Optional[int], str]:
+    """Aggregate a grouped plan over its live rows, or over the masked fact.
+
+    Returns ``(capacity, reason)``: the static length of the live-row list
+    (:func:`~repro.core.laq.aggregation.compact_capacity`), or ``None`` to
+    keep the masked lowering.  ``blocker`` names a property of the plan
+    that rules compaction out whatever its selectivity (a model head, a
+    streamed fact, an outer trace).
+    """
+    if blocker is not None:
+        return None, f"agg=masked({blocker})"
+    limit = planner_threshold("COMPACT_AGG_MAX_SELECTIVITY", platform)
+    if selectivity > limit:
+        return None, f"agg=masked(sel {selectivity:.3g} > {limit:g})"
+    cap = compact_capacity(n_rows, fact_capacity)
+    return cap, f"agg=compact(rows={n_rows}, cap={cap})"
 
 
 def estimate_query_cost(model: Optional[Model], fact_rows: int,

@@ -60,6 +60,7 @@ class Span(NamedTuple):
     request: int              # id of the root span
     programs: int             # backend compiles and cache loads
     compile_s: float          # trace + lower + compile-or-load seconds
+    attrs: Dict               # the keyword arguments the span was opened with
 
 
 class Total(NamedTuple):
@@ -122,7 +123,8 @@ class _Open(contextlib.ContextDecorator):
         dt = end - self.start
         with _lock:
             _ring.append((self.id, self.name, self.start, end, self.parent,
-                          self.request, self.programs, self.compile_s))
+                          self.request, self.programs, self.compile_s,
+                          self.attrs))
             t = _totals.get(self.name)
             if t is None:
                 _totals[self.name] = [1, dt, dt, self.programs,
@@ -137,7 +139,8 @@ class _Open(contextlib.ContextDecorator):
 
 def span(name: str, **attrs) -> _Open:
     """Record one span named ``name`` (``with span(...)``, or
-    ``@span(...)`` on a function); ``attrs`` annotate its profiler event."""
+    ``@span(...)`` on a function); ``attrs`` annotate its profiler event
+    and are kept on its record."""
     return _Open(name, attrs)
 
 

@@ -163,3 +163,19 @@ def test_spans_land_in_a_recorded_profile(session, calls, tmp_path):
     names = {e.name for plane in pd.planes if plane.name.startswith("/host:")
              for line in plane.lines for e in line.events}
     assert {"repro.query", "repro.query.dispatch"} <= names
+
+
+def test_a_compacted_group_by_records_one_compact_span(calls, session):
+    spans = calls["first"]
+    compact = [s for s in spans if s.name == "plan.compact"]
+    assert len(compact) == 1
+    groups = next(s for s in spans if s.name == "plan.groups")
+    assert compact[0].parent == groups.id
+    assert set(compact[0].attrs) == {"rows", "capacity"}
+    assert compact[0].attrs["rows"] <= compact[0].attrs["capacity"]
+    plan = session.compile(QUERY_IR["Q2.1"]())
+    assert (f"agg=compact(rows={compact[0].attrs['rows']}, "
+            f"cap={compact[0].attrs['capacity']})") in str(plan.explain())
+    ungrouped = _call(session, "Q1.1")
+    assert "plan.build" in {s.name for s in ungrouped}
+    assert "plan.compact" not in {s.name for s in ungrouped}
