@@ -131,6 +131,9 @@ class CompiledQuery:
     # tuple = no rule fired or rewrite="off").  ``query`` holds the
     # *rewritten* IR the plan executes; ``_source`` the query as written.
     _rewrites: Tuple[str, ...] = ()
+    # Backend of the fact-sized programs (``run``/``predictions``): "pallas"
+    # where they run a kernel (planner.plan_fact_backend), else "jnp".
+    fact_backend: str = "jnp"
 
     @property
     def is_traced(self) -> bool:
@@ -192,6 +195,7 @@ class CompiledQuery:
             trail=tuple(self._refresh_notes),
             shared_artifacts=tuple(self._pool_keys()),
             extras=(("selectivity", self.selectivity),
+                    ("fact_backend", self.fact_backend),
                     ("rewrites", self._rewrites),
                     ("stream", self._stream.describe()
                      if self._stream is not None else None)))
@@ -632,6 +636,14 @@ def _group_columns(catalog: Mapping[str, Table], q: PredictiveQuery,
     return cols, bounds
 
 
+def _head_attrs(model) -> Dict:
+    """A model head's kind and shape, as span attrs (``p`` is 0 for a
+    linear head, which has no tree nodes)."""
+    tree = isinstance(model, DecisionTreeGEMM)
+    return {"head": "tree" if tree else "linear", "k": model.k,
+            "l": model.l, "p": model.p if tree else 0}
+
+
 def _fact_row_bytes(fact: Table, q: PredictiveQuery, n_arms: int,
                     out_width: int) -> int:
     """Per-fact-row working-set bytes of the online program.
@@ -1042,16 +1054,28 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
                                           len(star.dims), fact.capacity)
     if why:
         plan = dataclasses.replace(plan, reason=f"{plan.reason}; {why}")
+    if join_backend != "gather":   # the dense join is its own lowering
+        fact_backend = "jnp"
+
+    if q.model is not None:
+        telemetry.annotate(backend=backend, fact_backend=fact_backend,
+                           fact_rows=fact.capacity, **_head_attrs(q.model))
 
     prefused = None
     partial_keys = ()
     if q.model is not None and backend == "fused":
-        if use_pool:
-            parts, h, partial_keys = pool.acquire_partials(
-                star.dims, q.model, chains=chains)
-            prefused = PrefusedStar(parts, h)
-        else:
-            prefused = prefuse(star, q.model)
+        rows_p = sum(d.dim.capacity for d in star.dims)
+        with telemetry.span("plan.prefuse", rows=rows_p,
+                            bytes=rows_p * q.model.l * 4,
+                            **_head_attrs(q.model)):
+            if use_pool:
+                parts, h, partial_keys = pool.acquire_partials(
+                    star.dims, q.model, chains=chains)
+                prefused = PrefusedStar(parts, h)
+            else:
+                prefused = prefuse(star, q.model)
+            if not traced:     # the span times the build, not its dispatch
+                jax.block_until_ready(prefused.partials)
 
     reduce_fn = (matmul_aggregate if agg_backend == "matmul"
                  else segment_aggregate)
@@ -1199,7 +1223,8 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
         _pool=pool if use_pool else None,
         _pool_refs=({"arms": arm_refs, "partials": tuple(partial_keys)}
                     if use_pool else {}),
-        _online_fn=_online, _stream=stream, _rewrites=rewrite_trail)
+        _online_fn=_online, _stream=stream, _rewrites=rewrite_trail,
+        fact_backend=fact_backend)
 
 
 def _make_predict_rows_sharded(star: StarJoin, model,

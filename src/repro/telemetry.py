@@ -96,7 +96,7 @@ class _Open(contextlib.ContextDecorator):
         self.attrs = attrs
 
     def _recreate_cm(self) -> "_Open":
-        return _Open(self.name, self.attrs)
+        return _Open(self.name, dict(self.attrs))
 
     def __enter__(self) -> "_Open":
         self.annotation = jax.profiler.TraceAnnotation(PREFIX + self.name,
@@ -142,6 +142,16 @@ def span(name: str, **attrs) -> _Open:
     ``@span(...)`` on a function); ``attrs`` annotate its profiler event
     and are kept on its record."""
     return _Open(name, attrs)
+
+
+def annotate(**attrs) -> None:
+    """Add ``attrs`` to the record of the innermost span open on this
+    thread (a decision made inside the span, known only once it is made).
+    They reach :func:`spans`, not the profiler event, which took the
+    attrs the span was opened with.  Nothing happens outside any span."""
+    stack = _stack()
+    if stack:
+        stack[-1].attrs.update(attrs)
 
 
 def spans() -> List[Span]:

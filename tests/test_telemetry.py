@@ -179,3 +179,59 @@ def test_a_compacted_group_by_records_one_compact_span(calls, session):
     ungrouped = _call(session, "Q1.1")
     assert "plan.build" in {s.name for s in ungrouped}
     assert "plan.compact" not in {s.name for s in ungrouped}
+
+
+def test_annotate_adds_to_the_innermost_span_only():
+    telemetry.reset()
+
+    @telemetry.span("outer", fixed=1)
+    def outer(i):
+        with telemetry.span("inner"):
+            telemetry.annotate(step=i)
+        telemetry.annotate(chosen=i)
+
+    outer(1)
+    outer(2)
+    telemetry.annotate(lost=True)        # outside any span: no effect
+    recs = [(s.name, s.attrs) for s in telemetry.spans()]
+    assert recs == [("inner", {"step": 1}), ("outer", {"fixed": 1,
+                                                       "chosen": 1}),
+                    ("inner", {"step": 2}), ("outer", {"fixed": 1,
+                                                       "chosen": 2})]
+
+
+def test_model_plans_record_their_side_and_prefuse(no_persistent_cache):
+    """A fused plan opens one ``plan.prefuse`` span inside its
+    ``plan.build``, with the head's shape and the partials' size; both
+    sides record their choice and fact backend on ``plan.build``."""
+    from repro.core.fusion import LinearOperator
+    from repro.core.query import PREDICTION, Session
+    from repro.data import table4_catalog
+
+    cat = table4_catalog(sf=1, k=6, seed=5, fact_rows=500)
+    sess = Session(cat)
+    w = jax.numpy.ones((6, 3), jax.numpy.float32)
+    q = (sess.query("fact")
+         .join("dim_b", on=("fk_b", "pk"), features=("b0", "b1"))
+         .join("dim_c", on=("fk_c", "pk"), features=("c0", "c1"))
+         .join("dim_d", on=("fk_d", "pk"), features=("d0", "d1"))
+         .predict(LinearOperator(w))
+         .group_by(("dim_d", "year", 7), num_groups=7)
+         .agg(s=("sum", PREDICTION)).build())
+    for backend in ("fused", "nonfused"):
+        telemetry.reset()
+        sess.bind(q).compile(backend=backend)
+        spans = telemetry.spans()
+        build = [s for s in spans if s.name == "plan.build"]
+        assert len(build) == 1
+        assert build[0].attrs == {"backend": backend, "fact_backend": "jnp",
+                                  "fact_rows": 500, "head": "linear",
+                                  "k": 6, "l": 3, "p": 0}
+        pre = [s for s in spans if s.name == "plan.prefuse"]
+        if backend == "nonfused":
+            assert pre == []
+            continue
+        rows = sum(cat[t].capacity for t in ("dim_b", "dim_c", "dim_d"))
+        assert len(pre) == 1 and pre[0].parent == build[0].id
+        assert pre[0].attrs == {"rows": rows, "bytes": rows * 3 * 4,
+                                "head": "linear", "k": 6, "l": 3, "p": 0}

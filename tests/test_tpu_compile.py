@@ -20,6 +20,10 @@ TOP_BUCKET = 512
 SF1_FACT_ROWS = 6_000_000
 # (k, depth) of the P3 and P4 tree heads (data/ssb_queries.py).
 TREES = {"P3": (5, 3), "P4": (3, 2)}
+# The paper's depth-7 trees over Table 4's sf-8 fact (Fig. 19's k = 16, and
+# k = 256 projected to the features its 127 nodes read, about 101).
+TABLE4_FACT_ROWS = 4_800_000
+TABLE4_TREES = {"k16": (16, 7), "k256-projected": (101, 7)}
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +68,18 @@ def test_fused_star_gather_compiles_for_v5e(one_chip, n):
 def test_tree_predict_compiles_for_v5e(one_chip, head, n):
     k, depth = TREES[head]
     p, l = 2 ** depth - 1, 2 ** depth
+    args = [_spec(one_chip, s) for s in
+            ((n, k), (k, p), (p,), (p, l), (l,))]
+    compiled = jax.jit(tree_predict).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("head", sorted(TABLE4_TREES))
+def test_tree_predict_compiles_for_v5e_at_table4_fact(one_chip, head):
+    """One call over the whole 4.8M-row fact: p = 127 nodes, l = 128
+    leaves (one leaf block)."""
+    k, depth = TABLE4_TREES[head]
+    n, p, l = TABLE4_FACT_ROWS, 2 ** depth - 1, 2 ** depth
     args = [_spec(one_chip, s) for s in
             ((n, k), (k, p), (p,), (p, l), (l,))]
     compiled = jax.jit(tree_predict).lower(*args).compile()
