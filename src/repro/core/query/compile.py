@@ -1060,6 +1060,11 @@ def compile_query(catalog: Mapping[str, Table], q: PredictiveQuery, *,
     if q.model is not None:
         telemetry.annotate(backend=backend, fact_backend=fact_backend,
                            fact_rows=fact.capacity, **_head_attrs(q.model))
+        if backend != "fused" and fact_backend == "pallas":
+            # tree_predict's row block over the fact-sized call
+            from repro.kernels.tree_predict.kernel import row_block
+            telemetry.annotate(block_rows=row_block(
+                fact.capacity, q.model.k, q.model.p))
 
     prefused = None
     partial_keys = ()

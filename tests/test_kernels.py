@@ -13,6 +13,9 @@ from repro.kernels import (fused_star_gather, fused_star_gather_ref,
                            onehot_matmul, onehot_matmul_ref, tree_predict,
                            tree_predict_ref)
 from repro.core.fusion import random_tree
+from repro.kernels.tree_predict.kernel import (ROW_BLOCK_CAP,
+                                               ROW_BLOCK_VMEM_BYTES,
+                                               _step_bytes, row_block)
 
 
 # ------------------------------------------------------------ onehot_matmul
@@ -146,20 +149,43 @@ def test_fused_star_gather_empty_batch():
 
 
 # --------------------------------------------------------------- tree_predict
-@pytest.mark.parametrize("n,k,depth", [(8, 4, 2), (130, 16, 4), (64, 256, 6),
-                                       (17, 3, 1)])
-def test_tree_predict_kernel_vs_ref(n, k, depth):
+@pytest.mark.parametrize("n,k,depth,block_n", [
+    (8, 4, 2, 8), (130, 16, 4, 8), (64, 256, 6, 8), (17, 3, 1, 8),
+    # The shape-chosen row block (None): ragged last blocks at depth 7 over
+    # Table 4's widths, one row, and one 8-row block.
+    (2049, 16, 7, None), (5000, 106, 7, None), (2049, 106, 7, None),
+    (5000, 16, 7, None), (1, 16, 7, None), (8, 106, 7, None)])
+def test_tree_predict_kernel_vs_ref(n, k, depth, block_n):
     rng = np.random.default_rng(n + k + depth)
     tree = random_tree(rng, k, depth)
     x = rng.normal(size=(n, k)).astype(np.float32)
     got = np.asarray(tree_predict(jnp.asarray(x), tree.F, tree.v, tree.H,
-                                  tree.h, block_n=8, block_l=128,
+                                  tree.h, block_n=block_n, block_l=128,
                                   interpret=True))
     want = np.asarray(tree_predict_ref(jnp.asarray(x), tree.F, tree.v,
                                        tree.H, tree.h))
     np.testing.assert_array_equal(got, want)
     # Exactly one leaf fires per row.
     np.testing.assert_array_equal(got.sum(axis=1), np.ones(n))
+
+
+def test_tree_predict_row_block_rule():
+    """The row block is a multiple of 8, never above n rounded up to 8;
+    Table 4's depth-7 trees over 4.8M rows take the cap; a depth-9 tree
+    over 512 features keeps its working set within the VMEM budget."""
+    for n in (1, 7, 8, 9, 100, 2047, 2049, 4_800_000):
+        for k, p in ((16, 127), (106, 127), (512, 511)):
+            bn = row_block(n, k, p)
+            assert bn % 8 == 0 and bn & (bn - 1) == 0, (n, k, p)
+            assert bn <= -(-n // 8) * 8, (n, k, p)
+    for k in (16, 106):
+        assert row_block(4_800_000, k, 127) == ROW_BLOCK_CAP
+    # 4096 rows at k 16 run out of a v5e's scoped VMEM: the budget refuses.
+    assert _step_bytes(4096, 16, 127, 128) > ROW_BLOCK_VMEM_BYTES
+    bn = row_block(4_800_000, 512, 511)
+    assert 8 <= bn < ROW_BLOCK_CAP
+    assert _step_bytes(bn, 512, 511, 128) <= ROW_BLOCK_VMEM_BYTES
+    assert _step_bytes(2 * bn, 512, 511, 128) > ROW_BLOCK_VMEM_BYTES
 
 
 def test_tree_predict_kernel_equals_model_apply():

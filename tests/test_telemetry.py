@@ -235,3 +235,40 @@ def test_model_plans_record_their_side_and_prefuse(no_persistent_cache):
         assert len(pre) == 1 and pre[0].parent == build[0].id
         assert pre[0].attrs == {"rows": rows, "bytes": rows * 3 * 4,
                                 "head": "linear", "k": 6, "l": 3, "p": 0}
+
+
+def test_nonfused_tree_plan_records_its_row_block(no_persistent_cache):
+    """A nonfused tree plan whose fact program runs ``tree_predict``
+    records on ``plan.build`` the row block of its fact-sized call, chosen
+    from the shapes; the plan's answer matches the plain jnp side."""
+    import numpy as np
+    from repro.core.fusion import random_tree
+    from repro.core.query import PREDICTION, Session
+    from repro.data import table4_catalog
+    from repro.kernels.tree_predict.kernel import row_block
+
+    cat = table4_catalog(sf=1, k=6, seed=5, fact_rows=500)
+    tree = random_tree(np.random.default_rng(3), 6, 3)
+    sess = Session(cat, interpret=True)
+    q = (sess.query("fact")
+         .join("dim_b", on=("fk_b", "pk"), features=("b0", "b1"))
+         .join("dim_c", on=("fk_c", "pk"), features=("c0", "c1"))
+         .join("dim_d", on=("fk_d", "pk"), features=("d0", "d1"))
+         .predict(tree)
+         .group_by(("dim_d", "year", 7), num_groups=7)
+         .agg(s=("sum", PREDICTION)).build())
+    answers = {}
+    for serve in ("pallas", "jnp"):
+        telemetry.reset()
+        plan = sess.bind(q).compile(backend="nonfused", serve_backend=serve)
+        answers[serve] = np.asarray(plan.run()["s"])
+        build = [s for s in telemetry.spans() if s.name == "plan.build"]
+        assert len(build) == 1
+        attrs = build[0].attrs
+        assert attrs["fact_backend"] == serve
+        if serve == "jnp":
+            assert "block_rows" not in attrs
+            continue
+        n = attrs["fact_rows"]
+        assert attrs["block_rows"] == row_block(n, tree.k, tree.p) == 256
+    np.testing.assert_array_equal(answers["pallas"], answers["jnp"])

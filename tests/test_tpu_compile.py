@@ -23,7 +23,10 @@ TREES = {"P3": (5, 3), "P4": (3, 2)}
 # The paper's depth-7 trees over Table 4's sf-8 fact (Fig. 19's k = 16, and
 # k = 256 projected to the features its 127 nodes read, about 101).
 TABLE4_FACT_ROWS = 4_800_000
-TABLE4_TREES = {"k16": (16, 7), "k256-projected": (101, 7)}
+# A wide tree besides: setting 2's depth 9 over k = 512 (p = 511, l = 512,
+# four leaf blocks), where the row block shrinks to fit VMEM.
+TABLE4_TREES = {"k16": (16, 7), "k256-projected": (101, 7),
+                "k512-depth9": (512, 9)}
 
 
 @pytest.fixture(scope="module")
@@ -76,8 +79,10 @@ def test_tree_predict_compiles_for_v5e(one_chip, head, n):
 
 @pytest.mark.parametrize("head", sorted(TABLE4_TREES))
 def test_tree_predict_compiles_for_v5e_at_table4_fact(one_chip, head):
-    """One call over the whole 4.8M-row fact: p = 127 nodes, l = 128
-    leaves (one leaf block)."""
+    """One call over the whole 4.8M-row fact, in the row blocks
+    ``row_block`` chooses from the shapes (the last one ragged): p = 127
+    nodes and l = 128 leaves (one leaf block), or the wide tree's 511 and
+    512."""
     k, depth = TABLE4_TREES[head]
     n, p, l = TABLE4_FACT_ROWS, 2 ** depth - 1, 2 ** depth
     args = [_spec(one_chip, s) for s in
