@@ -35,9 +35,9 @@ import time
 
 import numpy as np
 
-from repro.launch.serve import FusedFeatureServer
+from repro.core.query import Session
 
-from .common import emit, write_json
+from .common import emit, random_requests, serving_star, write_json
 
 
 def _pct(ts, p):
@@ -46,26 +46,30 @@ def _pct(ts, p):
 
 def run(scale: float = 0.05, requests: int = 240, k: int = 16, l: int = 4,
         slo_ms: float = 2.0, seed: int = 0):
-    server = FusedFeatureServer(setting=2, sf=1, k=k, l=l, scale=scale,
-                                seed=seed)
-    rt = server.runtime_fused
+    catalog, query, dim_rows = serving_star(k, l, scale, seed)
+    session = Session(catalog)
+    rt = session.bind(query).serve(backend="fused")
     rng = np.random.default_rng(seed + 1)
+
+    def draw(n):
+        return random_requests(query, dim_rows, n, rng)
+
     top = rt.buckets[-1]
     # Warm every bucket so neither loop ever traces mid-measurement.
     for n in [1] + list(rt.buckets):
-        server.serve_batch(server.random_requests(n, rng))
+        rt.serve(draw(n))
 
-    point = [server.random_requests(1, rng) for _ in range(requests)]
+    point = [draw(1) for _ in range(requests)]
 
     # -- closed loop: sequential synchronous point lookups ------------------
     t0 = time.perf_counter()
-    want = [np.asarray(server.serve_batch(r)) for r in point]
+    want = [np.asarray(rt.serve(r)) for r in point]
     closed_s = time.perf_counter() - t0
     qps_closed = requests / closed_s
     emit("scheduler/closed_loop/us_per_req", closed_s / requests * 1e6,
          f"qps={qps_closed:.0f};n={requests}")
 
-    plan = server.scheduled(slo_ms=slo_ms)
+    plan = session.scheduler(slo_ms=slo_ms).register(rt, name="fused")
 
     # -- open-loop burst: all requests in flight at once --------------------
     t0 = time.perf_counter()
@@ -105,12 +109,12 @@ def run(scale: float = 0.05, requests: int = 240, k: int = 16, l: int = 4,
          f"p50_us={_pct(lat, 50):.0f};p95_us={_pct(lat, 95):.0f}")
 
     # -- point lookups while an analytical scan is in flight ----------------
-    scan = server.random_requests(8 * top, rng)
-    want_scan = np.asarray(server.serve_batch(scan))
+    scan = draw(8 * top)
+    want_scan = np.asarray(rt.serve(scan))
     f_scan = plan.submit(scan, lane="batch")
     ilat = []
     while not f_scan.done():
-        r = server.random_requests(1, rng)
+        r = draw(1)
         t0 = time.perf_counter()
         np.asarray(plan.submit(r).result(120))
         ilat.append(time.perf_counter() - t0)
@@ -123,7 +127,7 @@ def run(scale: float = 0.05, requests: int = 240, k: int = 16, l: int = 4,
     print(f"[bench] scheduler steps={st['steps']} "
           f"admitted={st['admitted_rows']} padded={st['padded_rows']} "
           f"rejected={st['rejected']}", flush=True)
-    return server, plan
+    return session, plan
 
 
 def main():
@@ -135,10 +139,10 @@ def main():
     ap.add_argument("--slo-ms", type=float, default=2.0)
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
-    server, plan = run(scale=args.scale, requests=args.requests, k=args.k,
-                       l=args.l, slo_ms=args.slo_ms)
+    session, plan = run(scale=args.scale, requests=args.requests, k=args.k,
+                        l=args.l, slo_ms=args.slo_ms)
     stats = plan.stats()
-    server.session.scheduler().close()
+    session.scheduler().close()
     if args.json:
         write_json(args.json, {"bench": "scheduler", "scheduler": stats})
 

@@ -1,9 +1,9 @@
 """Dynamic-batch serving latency across padding buckets and backends.
 
-One ``compile_serving`` plan per backend serves a ragged sweep of request
-batch sizes; every size lands in one of the fixed padding buckets, so the
-steady state never recompiles.  Emits per-size medians plus the runtime's
-own per-bucket percentiles — the serving-side counterpart of
+One serving runtime per backend (``Session.bind(q).serve``) serves a ragged
+sweep of request batch sizes; every size lands in one of the fixed padding
+buckets, so the steady state never recompiles.  Emits per-size medians plus
+the runtime's own per-bucket percentiles — the serving-side counterpart of
 ``bench_predictive_queries`` (which measures whole-query aggregation).
 
 Run:  PYTHONPATH=src python -m benchmarks.bench_serving
@@ -16,9 +16,9 @@ import argparse
 
 import numpy as np
 
-from repro.launch.serve import FusedFeatureServer
+from repro.core.query import Session
 
-from .common import bench, emit, write_json
+from .common import bench, emit, random_requests, serving_star, write_json
 
 
 def run(
@@ -29,26 +29,20 @@ def run(
     interpret: bool = False,
     seed: int = 0,
 ):
-    server = FusedFeatureServer(
-        setting=2,
-        sf=1,
-        k=k,
-        l=l,
-        scale=scale,
-        seed=seed,
-        serve_backend=serve_backend,
-        interpret=interpret,
-    )
+    catalog, query, dim_rows = serving_star(k, l, scale, seed)
+    builder = Session(catalog, interpret=interpret).bind(query)
+    runtimes = {
+        name: builder.serve(backend=name, serve_backend=serve_backend)
+        for name in ("fused", "nonfused")
+    }
     rng = np.random.default_rng(seed + 1)
-    buckets = server.runtime_fused.buckets
+    buckets = runtimes["fused"].buckets
     sizes = sorted({max(1, b // 2) for b in buckets} | set(buckets))
     sizes.append(2 * buckets[-1] + 3)  # oversize: served in top-bucket chunks
-    for fused in (True, False):
-        name = "fused" if fused else "nonfused"
-        runtime = server.runtime(fused)
+    for name, runtime in runtimes.items():
         for n in sizes:
-            reqs = server.random_requests(n, rng)
-            us = bench(server.serve_batch, reqs, fused)
+            reqs = random_requests(query, dim_rows, n, rng)
+            us = bench(runtime.serve, reqs)
             emit(
                 f"serving/{name}/n{n}",
                 us,
@@ -59,7 +53,7 @@ def run(
             float(runtime.num_compiles),
             f"traces for {len(sizes)} batch sizes",
         )
-    return server
+    return runtimes
 
 
 def main():
@@ -71,7 +65,7 @@ def main():
     ap.add_argument("--interpret", action="store_true")
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
-    server = run(
+    runtimes = run(
         scale=args.scale,
         k=args.k,
         l=args.l,
@@ -79,10 +73,7 @@ def main():
         interpret=args.interpret,
     )
     if args.json:
-        latency = {
-            "fused": server.runtime_fused.latency_stats(),
-            "nonfused": server.runtime_nonfused.latency_stats(),
-        }
+        latency = {name: rt.latency_stats() for name, rt in runtimes.items()}
         write_json(args.json, {"bench": "serving", "latency": latency})
 
 

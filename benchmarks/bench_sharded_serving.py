@@ -1,7 +1,7 @@
 """Sharded vs single-device serving latency across mesh shapes.
 
-One ``FusedFeatureServer`` per mesh shape serves identical request batches
-through the single-device runtime and the ``shard_map`` runtime (partials
+One ``Session`` per mesh shape serves identical request batches through
+the single-device fused runtime and the ``shard_map`` runtime (partials
 row-sharded over the model axis, batches over the data axis), emitting
 per-size medians plus each runtime's per-bucket percentiles — the scaling
 counterpart of ``bench_serving``.
@@ -27,38 +27,34 @@ import sys
 def run(mesh_shapes, scale: float, k: int, l: int, seed: int = 0):
     import numpy as np
 
-    from repro.launch.mesh import make_serving_mesh
-    from repro.launch.serve import FusedFeatureServer
+    from repro.core.query import Session, make_serving_mesh
 
-    from .common import bench, emit
+    from .common import bench, emit, random_requests, serving_star
 
-    base = FusedFeatureServer(setting=2, sf=1, k=k, l=l, scale=scale,
-                              seed=seed)
+    catalog, query, dim_rows = serving_star(k, l, scale, seed)
+    base = Session(catalog).bind(query).serve(backend="fused")
     rng = np.random.default_rng(seed + 1)
-    buckets = base.runtime_fused.buckets
+    buckets = base.buckets
     sizes = sorted({max(1, b // 2) for b in buckets} | set(buckets))
     sizes.append(2 * buckets[-1] + 3)   # oversize: top-bucket chunks
-    requests = {n: base.random_requests(n, rng) for n in sizes}
+    requests = {n: random_requests(query, dim_rows, n, rng) for n in sizes}
 
     for n in sizes:
-        us = bench(base.serve_batch, requests[n], True)
+        us = bench(base.serve, requests[n])
         emit(f"sharded_serving/mesh1x1ref/n{n}", us, "single-device fused")
 
-    servers = {}
+    runtimes = {}
     for shape in mesh_shapes:
-        mesh = make_serving_mesh(shape)
-        server = FusedFeatureServer(setting=2, sf=1, k=k, l=l, scale=scale,
-                                    seed=seed, mesh=mesh,
-                                    shard_threshold_bytes=0)
-        servers[shape] = server
-        rt = server.runtime_fused
+        sess = Session(catalog, mesh=make_serving_mesh(shape),
+                       shard_threshold_bytes=0)
+        rt = runtimes[shape] = sess.bind(query).serve(backend="fused")
         tag = f"mesh{shape[0]}x{shape[1]}"
         for n in sizes:
-            us = bench(server.serve_batch, requests[n], True)
+            us = bench(rt.serve, requests[n])
             # Identical math: the sharded runtime must match the reference.
             np.testing.assert_array_equal(
-                np.asarray(server.serve_batch(requests[n], True)),
-                np.asarray(base.serve_batch(requests[n], True)))
+                np.asarray(rt.serve(requests[n])),
+                np.asarray(base.serve(requests[n])))
             emit(f"sharded_serving/{tag}/n{n}", us,
                  f"sharded={rt.sharded.num_sharded}/{len(rt.sharded.arms)}"
                  f";buckets={rt.buckets}")
@@ -67,7 +63,7 @@ def run(mesh_shapes, scale: float, k: int, l: int, seed: int = 0):
              "quasi-static bytes resident per device")
         emit(f"sharded_serving/{tag}/compiles", float(rt.num_compiles),
              f"traces for {len(sizes)} batch sizes")
-    return base, servers
+    return base, runtimes
 
 
 def main():
@@ -96,14 +92,13 @@ def main():
         half = n // 2
         if half > 1:
             shapes += [(2, half)]
-    base, servers = run(shapes, args.scale, args.k, args.l)
+    base, runtimes = run(shapes, args.scale, args.k, args.l)
     if args.json:
         from .common import write_json
 
-        latency = {"ref": base.runtime_fused.latency_stats()}
-        for shape, server in servers.items():
-            latency[f"mesh{shape[0]}x{shape[1]}"] = (
-                server.runtime_fused.latency_stats())
+        latency = {"ref": base.latency_stats()}
+        for shape, rt in runtimes.items():
+            latency[f"mesh{shape[0]}x{shape[1]}"] = rt.latency_stats()
         write_json(args.json, {"bench": "sharded_serving",
                                "devices": n, "latency": latency})
 

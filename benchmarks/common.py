@@ -1,4 +1,4 @@
-"""Benchmark harness utilities: timing, CSV/JSON output."""
+"""Benchmark harness utilities: timing, CSV/JSON output, serving workload."""
 from __future__ import annotations
 
 import json
@@ -6,7 +6,12 @@ import time
 from typing import Callable, List, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+
+from repro.core.fusion import LinearOperator
+from repro.core.query import Catalog, query_from_star
+from repro.data import generate_star
 
 HEADER = "name,us_per_call,derived"
 _rows: List[str] = []
@@ -58,3 +63,28 @@ def bench(fn: Callable, *args, warmup: int = 2, iters: int = 7) -> float:
         jax.block_until_ready(fn(*args))
         times.append(time.perf_counter() - t0)
     return float(np.min(times) * 1e6)
+
+
+def serving_star(k: int, l: int, scale: float, seed: int):
+    """The serving benches' workload: Table 4 setting 2 at sf 1 with a
+    random ``(k, l)`` linear head.
+
+    Returns the versioned catalog, the star query over it, and the
+    dimension row counts :func:`random_requests` draws keys from.
+    """
+    rng = np.random.default_rng(seed)
+    syn = generate_star(2, 1, k, seed=seed, scale=scale)
+    model = LinearOperator(
+        jnp.asarray(rng.normal(size=(k, l)).astype(np.float32)))
+    tables, query = query_from_star(syn.star, model=model)
+    return Catalog(tables), query, syn.dim_rows
+
+
+def random_requests(query, dim_rows, n: int, rng: np.random.Generator):
+    """A batch of ``n`` per-arm FK requests drawn from the key ranges."""
+    reqs = {}
+    for arm, rows in zip(query.arms, dim_rows):
+        # ~1/16 of keys miss the dimension: exercises not-found masking.
+        keys = rng.integers(0, max(int(rows * 17 / 16), 1), size=n)
+        reqs[arm.fk_col] = keys.astype(np.int32)
+    return reqs

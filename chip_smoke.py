@@ -255,9 +255,9 @@ def serving_phase(jax, data, seed) -> None:
 
 
 def sharded_phase(jax, data, seed, chips) -> None:
-    from repro.core.query import Session, requests_from_rows
+    from repro.core.query import (Session, make_serving_mesh,
+                                  requests_from_rows)
     from repro.data import QUERY_IR, ssb_catalog, ssb_session
-    from repro.launch.mesh import make_serving_mesh
 
     single = ssb_session(data)
     fact = single.catalog["lineorder"]

@@ -38,8 +38,7 @@ import numpy as np
 from repro.compile_cache import init_compile_cache
 from repro.core.fusion import LinearOperator
 from repro.core.laq import Table
-from repro.core.query import PREDICTION, Catalog, Session
-from repro.launch.mesh import make_serving_mesh
+from repro.core.query import PREDICTION, Catalog, Session, make_serving_mesh
 
 init_compile_cache()
 rng = np.random.default_rng(0)

@@ -278,6 +278,7 @@ from .ir import (AGG_OPS, COUNT_STAR, FILTER_OPS, PREDICTION, Aggregate,
 from .compile import CompiledQuery, compile_query, query_from_star
 from .rewrite import RewriteResult, rewrite_query
 from .explain import ExplainReport
+from .mesh import make_serving_mesh
 from .snowflake import (CollapsedChain, chain_tables, materialize_chains,
                         resolve_chain, virtual_name)
 from .workload import FuzzCase, FuzzReport, generate_case, np_oracle, run_fuzz
@@ -325,6 +326,6 @@ __all__ = [
     "AdmissionScheduler", "ScheduledPlan", "SchedulerBackpressureError",
     "SchedulerClosedError", "DEFAULT_MAX_QUEUED_ROWS", "DEFAULT_SLO_MS",
     "LANES",
-    "QueryBuilder", "Session", "query", "query_key",
+    "QueryBuilder", "Session", "make_serving_mesh", "query", "query_key",
     "ShardedArm", "ShardedPrefusedPartials", "shard_prefused_partials",
 ]

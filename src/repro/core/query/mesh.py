@@ -1,32 +1,19 @@
-"""Production mesh construction.
+"""Device meshes for sharded serving, and the divisibility-safe specs on them.
 
-A function, not a module-level constant — importing this module must never
-touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
+A function, not a module-level constant: importing this module never touches
+jax device state, so a caller may still force host devices with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before jax starts.
 
-Single pod: (16, 16) = 256 chips, axes (data, model).
-Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model); the pod axis
-is data-parallel across the (slower) inter-pod links — gradient all-reduce
-is hierarchical: reduce-scatter inside pods, all-reduce across pods.
+The serving runtime shards prefused partials over ``"model"`` and request
+batches over the data-parallel axes (``"pod"`` and ``"data"``, where
+present).
 """
 from __future__ import annotations
 
 import math
 
 import jax
-from jax.sharding import AxisType
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(model_parallel: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU examples)."""
-    n = len(jax.devices())
-    mp = min(model_parallel, n)
-    return make_serving_mesh((n // mp, mp))
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def make_serving_mesh(shape, axes=("data", "model")):
@@ -62,3 +49,21 @@ def dp_size(mesh) -> int:
     for a in dp_axes(mesh):
         out *= mesh.shape[a]
     return out
+
+
+def _div(mesh, dim: int, axis) -> bool:
+    if axis is None:
+        return True
+    axes = (axis,) if isinstance(axis, str) else axis
+    total = 1
+    for a in axes:
+        if a not in mesh.axis_names:
+            return False
+        total *= mesh.shape[a]
+    return dim % total == 0
+
+
+def safe_spec(mesh, shape, *axes):
+    """PartitionSpec with divisibility fallback per dim."""
+    return P(*[a if _div(mesh, d, a) else None
+               for d, a in zip(shape, axes)])

@@ -23,11 +23,11 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from ...kernels.fused_star_gather.kernel import max_rows_per_call
-from ...launch.sharding import safe_spec
 from ..fusion.operators import DecisionTreeGEMM
 from ..fusion.planner import FusionDecision, plan_fusion
 from ..laq.aggregation import compact_capacity
 from .ir import Model
+from .mesh import safe_spec
 
 # Cost-model thresholds, keyed by ``jax.default_backend()`` with the
 # CPU-bench-seeded values as the default row — making TPU calibration a

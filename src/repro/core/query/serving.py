@@ -68,7 +68,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...launch.mesh import dp_size
 from ..fusion.operators import DecisionTreeGEMM
 from ..fusion.pipeline import prefuse_dims, prefuse_rows
 from ..laq.catalog import Catalog, CatalogHistoryError, changed_spans
@@ -78,6 +77,7 @@ from ..laq.star import DimSpec
 from ..laq.table import PAD_KEY, Table
 from .explain import ExplainReport
 from .ir import PredictiveQuery
+from .mesh import dp_size
 from .multiquery import holds_tracers
 from .snowflake import CollapsedChain, chain_tables, resolve_chain
 from .planner import (QueryPlan, effective_serve_backend, place_tables,

@@ -16,7 +16,7 @@ a mesh and rebuilds the online phase as one ``shard_map``-jitted program:
   (the tree compare vector ``h``, the non-fused model head) replicates.
 * **Placement is planned, not fixed** (`plan_partition_spec`): partials
   below a byte threshold replicate, larger ones shard row-wise via
-  ``launch.sharding.safe_spec`` — a row count that doesn't divide the mesh
+  ``mesh.safe_spec`` — a row count that doesn't divide the mesh
   axis degrades to replication instead of failing.
 
 Bit-exactness: the owning shard contributes the identical fp32 row the
@@ -41,9 +41,9 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ...launch.mesh import dp_axes
 from ..fusion.operators import DecisionTreeGEMM, LinearOperator
 from ..laq.join import PKIndex, pk_index, shard_pk_index
+from .mesh import dp_axes
 
 
 def _shard_map(f, mesh, in_specs, out_specs):

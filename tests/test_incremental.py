@@ -27,9 +27,8 @@ from repro.core.laq import (PAD_KEY, Catalog, CatalogReadOnlyError,
                             DomainCache, Table, pk_index)
 from repro.core.query import (PREDICTION, Aggregate, ArmSpec, GroupKey,
                               PredictiveQuery, Session, compile_query,
-                              compile_serving)
+                              compile_serving, make_serving_mesh)
 from repro.core.laq.selection import Pred
-from repro.launch.mesh import make_serving_mesh
 
 needs_8_devices = pytest.mark.skipif(
     len(jax.devices()) < 8,

@@ -17,7 +17,7 @@ from repro.core.fusion import LinearOperator
 from repro.core.fusion.operators import reference_tree_eval, tree_from_arrays
 from repro.core.query import PREDICTION, Session
 from repro.core.query.workload import np_oracle, np_row_oracle
-from repro.data import feature_widths, table4_catalog
+from repro.data import feature_widths, generate_star, table4_catalog
 
 FACT_ROWS = 21_000
 YEARS = 7
@@ -149,3 +149,10 @@ def test_k256_tree_leaves_match_direct_traversal(catalog):
     x = np.concatenate(feats, axis=1)
     np.testing.assert_array_equal(onehot.argmax(axis=1),
                                   reference_tree_eval(feature, threshold, x))
+
+
+def test_synthetic_star_shapes():
+    s = generate_star(setting=2, sf=1, k=12, scale=0.1)
+    assert s.star.feature_width == 12
+    t = s.star.materialize()
+    assert t.shape[1] == 12

@@ -36,8 +36,7 @@ from repro.core.query import (Aggregate, AdmissionScheduler, ArmSpec,
                               PREDICTION, PredictiveQuery, ScheduledPlan,
                               SchedulerBackpressureError,
                               SchedulerClosedError, SentinelKeyError,
-                              Session, compile_serving)
-from repro.launch.mesh import make_serving_mesh
+                              Session, compile_serving, make_serving_mesh)
 
 needs_8_devices = pytest.mark.skipif(
     len(jax.devices()) < 8,

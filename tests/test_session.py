@@ -192,7 +192,7 @@ def test_session_rows_and_serve_match_old_entry_points(data, catalog):
 def test_mesh_override_does_not_collide_in_plan_cache(catalog):
     """A per-call mesh override must compile a sibling plan, not return the
     cached meshless one (and vice versa)."""
-    from repro.launch.mesh import make_serving_mesh
+    from repro.core.query import make_serving_mesh
     sess = Session(catalog)
     q = QUERY_IR["P1.linear.year"]()
     meshless = sess.compile(q)

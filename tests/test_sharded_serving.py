@@ -30,13 +30,13 @@ from repro.core.laq import shard_pk_index, shard_rows
 from repro.core.query import (
     compile_query,
     compile_serving,
+    make_serving_mesh,
     plan_partition_spec,
     plan_query,
     requests_from_rows,
 )
 from repro.data import QUERY_IR, generate_ssb, predictive_query_names, ssb_catalog
-from repro.launch.mesh import make_serving_mesh
-from repro.launch.sharding import param_pspec, safe_spec
+from repro.core.query.mesh import dp_axes, dp_size, safe_spec
 
 PRED_NAMES = predictive_query_names()
 BUCKETS = (8, 32)
@@ -284,7 +284,7 @@ def test_shard_pk_index_and_shard_rows_validate():
     assert shard_rows(jnp.zeros((12, 2)), 4).shape == (4, 3, 2)
 
 
-# -------------------------------- safe_spec / param_pspec fallback (15-on-16)
+# ------------------------------------------- safe_spec fallback (15-on-16)
 def _stub_mesh(**axes):
     """A mesh stand-in for divisibility logic (no devices needed)."""
     return types.SimpleNamespace(axis_names=tuple(axes), shape=dict(axes))
@@ -301,14 +301,18 @@ def test_safe_spec_divisibility_fallback():
     assert safe_spec(mesh, (8, 4), ("pod", "data"), None) == P(None, None)
 
 
-def test_param_pspec_divisibility_fallback():
-    mesh = _stub_mesh(pod=1, data=2, model=16)
-    cfg = types.SimpleNamespace(moe=None)
-    # 15 attention heads' worth of columns on a 16-way model axis.
-    assert param_pspec("blocks/0/attn/wq", (4, 64, 15), mesh, cfg) == P(
-        None, ("pod", "data"), None)
-    assert param_pspec("blocks/0/attn/wq", (4, 64, 32), mesh, cfg) == P(
-        None, ("pod", "data"), "model")
+def test_serving_mesh_data_parallel_axes():
+    """Request batches shard over the data axes: pod folds into data."""
+    assert dp_axes(_stub_mesh(data=2, model=4)) == ("data",)
+    assert dp_size(_stub_mesh(data=2, model=4)) == 2
+    assert dp_axes(_stub_mesh(pod=2, data=4, model=2)) == ("pod", "data")
+    assert dp_size(_stub_mesh(pod=2, data=4, model=2)) == 8
+    assert dp_size(_stub_mesh(model=8)) == 1
+    mesh = make_serving_mesh((1, 1))
+    assert mesh.axis_names == ("data", "model") and dp_size(mesh) == 1
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="needs"):
+        make_serving_mesh((n + 1, 1))
 
 
 def test_plan_partition_spec_applies_fallback_to_partials():

@@ -99,3 +99,13 @@ def test_q41_profit_total_matches_bruteforce(data):
     assert int(got["rows"]) == nrows
     assert float(np.asarray(got["profit"]).sum()) == pytest.approx(
         total, rel=1e-4)
+
+
+def test_ssb_generator_and_query_sanity():
+    data = generate_ssb(sf=1, scale=0.002, seed=0)
+    res = QUERIES["Q1.1"](data)
+    assert float(res["rows"]) > 0
+    assert np.isfinite(float(res["revenue"]))
+    res4 = QUERIES["Q4.2"](data)
+    n_groups_hit = int(np.sum(np.asarray(res4["profit"]) != 0))
+    assert n_groups_hit > 0
